@@ -85,6 +85,7 @@ def _stream_rows():
     from repro.core.engine import DeviceEngine, build_p2p_stream_tables
     from repro.core.engine.p2p import (p2p_bucket_vals, p2p_stream_gathered,
                                        stream_payload)
+    from repro.core.engine.schedules import stream_out_index
     from repro.kernels.p2p_stream import p2p_stream
     n = int(os.environ.get("ENGINE_BENCH_N", "1500"))
     nparts = int(os.environ.get("ENGINE_BENCH_PARTS", "4"))
@@ -125,8 +126,8 @@ def _stream_rows():
             np.add.at(phi_g, b["t_idx"][r][sel], v[r][sel])
     phi_s = np.zeros(flat)
     sv = np.asarray(p2p_stream_gathered(meta, payload, block_t=bt, smax=smax))
-    ok = stream["out_valid"]
-    np.add.at(phi_s, stream["out_idx"][ok], sv[ok])
+    out_idx, ok = stream_out_index(stream["meta"], stream["tgt_range"], bt)
+    np.add.at(phi_s, out_idx[ok], sv[ok])
     err = float(np.max(np.abs(phi_g - phi_s)))
 
     kernel_mode = "interpret" if ops.INTERPRET else "compiled"

@@ -48,6 +48,9 @@ def main(argv=None) -> None:
         print(f"no benchmark matches {filters}; "
               f"labels: {[l for l, _ in MODULES]}", file=sys.stderr)
         sys.exit(2)
+    from repro.launch.compile_cache import configure_compile_cache
+    configure_compile_cache(os.path.join(os.path.dirname(
+        os.path.abspath(__file__)), ".."))
     print("name,us_per_call,derived")
     failures = 0
     collected = []

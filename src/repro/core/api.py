@@ -445,6 +445,22 @@ def _rebind_remote(rb: RemoteBlock, let: LETData) -> RemoteBlock:
                        margin=rb.margin)
 
 
+def _batched_multipoles(ops, trees, scheds) -> list:
+    """Every partition's multipoles from ONE batched upward launch (the
+    engine's `batched_upward`, bitwise-equal per partition to
+    `fmm.upward_pass`).  Per-tree passes would compile a program per tree
+    and level shape, which dominates planning on an accelerator."""
+    if all(t is None for t in trees):
+        return [None] * len(trees)
+    from repro.core.engine.schedules import build_batched_upward, stack_bodies
+    from repro.core.engine.upward import batched_upward
+    up = build_batched_upward(trees, scheds)
+    M = np.asarray(batched_upward(ops, *stack_bodies(trees, up.n_bodies_max),
+                                  up))
+    return [None if s is None else M[p, :s.n_cells].copy()
+            for p, s in enumerate(scheds)]
+
+
 def plan_geometry(x, q, spec: PartitionSpec | None = None,
                   **overrides) -> GeometryPlan:
     """Layer 1: partition, build local trees, extract every LET (one batched
@@ -467,19 +483,18 @@ def plan_geometry(x, q, spec: PartitionSpec | None = None,
 
         # --- completely local trees (local bounding box, tight cells; §3) --
         with obs.span("plan.trees"):
-            owners, trees, scheds, Ms = [], [], [], []
+            owners, trees, scheds = [], [], []
             for pid in range(P):
                 idx = np.nonzero(part == pid)[0]
                 owners.append(idx)
                 if len(idx) == 0:
                     trees.append(None)
                     scheds.append(None)
-                    Ms.append(None)
                     continue
                 t = build_tree(x[idx], q[idx], ncrit=spec.ncrit)
                 trees.append(t)
                 scheds.append(build_tree_schedules(t))
-                Ms.append(np.asarray(upward_pass(t, ops, sched=scheds[-1])))
+            Ms = _batched_multipoles(ops, trees, scheds)
 
         # --- sender-initiated LET extraction: all remote boxes per sender in
         #     one batched frontier pass; empty partitions neither send nor

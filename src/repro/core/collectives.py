@@ -35,9 +35,7 @@ def _axis_index(axis_name):
 
 
 def _axis_size(axis_name):
-    if hasattr(jax.lax, "axis_size"):      # jax >= 0.6
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)      # older jax
+    return jax.lax.axis_size(axis_name)
 
 
 def _pvary(x, axis_name):

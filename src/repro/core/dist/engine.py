@@ -18,6 +18,10 @@ between the upward pass and the far field:
                         the received *halo* rows (`M_src = [local | halo]`),
                         then the same downward sweep / leaf evaluation.
 
+Steps 1 and 3 are each one jitted program shared by every protocol of a
+geometry (`_pack`, `_far`); only the small exchange program of step 2 is
+compiled per protocol, so a protocol sweep compiles the FMM phases once.
+
 Each phase returns the engine's padded f32 value tables; the host f64
 accumulation is identical to `DeviceEngine.evaluate`'s non-x64 path, which
 is what pins phi parity (the acceptance tolerance) across all protocols.
@@ -32,6 +36,8 @@ asserted at program build time and again in tests.
 """
 from __future__ import annotations
 
+from functools import partial
+
 import numpy as np
 
 import jax
@@ -40,7 +46,8 @@ import jax.numpy as jnp
 from repro import obs
 from repro.core.dist import programs as prog_mod
 from repro.core.dist.layout import build_wire_layout, build_wire_tables
-from repro.core.fmm import _p2p_vals
+from repro.core.engine.m2l import m2l_accumulate
+from repro.core.engine.p2p import p2p_rows_vals
 from repro.core.multipole import get_operators
 
 __all__ = ["ShardedEngine"]
@@ -63,6 +70,94 @@ def _pad_rank_rows(rows: dict, cap: int, fills: dict) -> dict:
             a.dtype)
         out[k] = np.concatenate([a, pad], axis=0) if n else pad.copy()
     return out
+
+
+def _rank_spec(mesh):
+    from jax.sharding import PartitionSpec as PS
+    return PS(mesh.axis_names[0])
+
+
+# The two compute programs of a sharded evaluation take no protocol: every
+# protocol of one geometry shares their compiled executables, and only the
+# small exchange program between them differs (ShardedEngine._exchange).
+@partial(jax.jit, static_argnames=("mesh", "ops", "n_cells", "ppr"))
+def _pack(x, q, pt, rt, *, mesh, ops, n_cells, ppr):
+    """Upward pass, then the dynamic words (multipoles, bodies) of every LET
+    span a rank originates packed into its wire pool.
+    -> (M_flat (D*ppr*n_cells, nk), pool (D, W))."""
+    from repro.core.engine.upward import batched_upward_kernel
+
+    def rank_pack(x, q, pt, rt):
+        M = batched_upward_kernel(
+            ops, x, q, pt["leaves"], pt["leaf_mask"], pt["leaf_centers"],
+            pt["leaf_idx"], pt["leaf_valid"], pt["up_ids"], pt["up_parents"],
+            pt["up_mask"], pt["up_d"], n_cells=n_cells)
+        M_flat = M.reshape(ppr * n_cells, ops.nk)
+        src_vec = jnp.concatenate([M_flat.reshape(-1), x.reshape(-1),
+                                   q.reshape(-1)])
+        pool = rt["pool_template"][0]
+        pool = pool.at[rt["pack_dst"][0]].set(src_vec[rt["pack_src"][0]])
+        return M_flat, pool[None]
+
+    spec = _rank_spec(mesh)
+    return jax.shard_map(rank_pack, mesh=mesh, in_specs=(spec,) * 4,
+                         out_specs=(spec, spec), check_vma=False)(x, q, pt, rt)
+
+
+@partial(jax.jit,
+         static_argnames=("mesh", "ops", "n_cells", "ppr", "n_buckets"))
+def _far(x, q, M_flat, pool, pt, rt, *, mesh, ops, n_cells, ppr, n_buckets):
+    """Far field over [local | halo] sources, the downward sweep, L2P, P2P
+    and M2P of every rank, reading the received halo rows out of the
+    exchanged pool.  -> the padded f32 value tables (L2P, each P2P bucket,
+    M2P when present)."""
+    def rank_far(x, q, M_flat, pool, pt, rt):
+        pool = pool[0]
+        M_halo = pool[rt["halo_M_idx"][0]]
+        x_halo = pool[rt["halo_x_idx"][0]]
+        q_halo = pool[rt["halo_q_idx"][0]]
+        M_src = jnp.concatenate([M_flat, M_halo], axis=0)
+        L_flat = jnp.zeros((ppr * n_cells, ops.nk), jnp.float32)
+        if "m2l_src" in rt:
+            L_flat = m2l_accumulate(ops, M_src, L_flat, rt["m2l_src"][0],
+                                    rt["m2l_tgt"][0], rt["m2l_d"][0],
+                                    rt["m2l_mask"][0])
+        L = L_flat.reshape(ppr, n_cells, ops.nk)
+
+        def l2l_one(Lp, ids, parents, mask, d):
+            return Lp.at[ids].add(ops.l2l_v(Lp[parents], d) * mask[:, None])
+
+        for lvl in range(pt["down_ids"].shape[1]):
+            L = jax.vmap(l2l_one)(L, pt["down_ids"][:, lvl],
+                                  pt["down_parents"][:, lvl],
+                                  pt["down_mask"][:, lvl],
+                                  pt["down_d"][:, lvl])
+
+        def l2p_one(Lp, xp, lf, lm, lc, li):
+            return ops.l2p_v(Lp[lf], xp[li], lc) * lm[:, None]
+
+        outs = [jax.vmap(l2p_one)(L, x, pt["leaves"], pt["leaf_mask"],
+                                  pt["leaf_centers"], pt["leaf_idx"])]
+        x_flat = x.reshape(-1, 3)
+        x_src = jnp.concatenate([x_flat, x_halo], axis=0)
+        q_src = jnp.concatenate([q.reshape(-1), q_halo], axis=0)
+        for bi in range(n_buckets):
+            outs.append(p2p_rows_vals(
+                x_flat, x_src, q_src, rt[f"pb{bi}_t_idx"][0],
+                rt[f"pb{bi}_s_idx"][0], rt[f"pb{bi}_s_valid"][0],
+                rt[f"pb{bi}_mask"][0]))
+        if "m2p_b" in rt:
+            outs.append(ops.m2p_v(M_src[rt["m2p_b"][0]],
+                                  x_flat[rt["m2p_t_idx"][0]],
+                                  rt["m2p_centers"][0])
+                        * rt["m2p_mask"][0][:, None])
+        return tuple(outs)
+
+    spec = _rank_spec(mesh)
+    n_outs = 1 + n_buckets + (1 if "m2p_b" in rt else 0)
+    return jax.shard_map(rank_far, mesh=mesh, in_specs=(spec,) * 6,
+                         out_specs=(spec,) * n_outs, check_vma=False)(
+        x, q, M_flat, pool, pt, rt)
 
 
 class ShardedEngine:
@@ -98,9 +193,9 @@ class ShardedEngine:
                                       n_cells_max=Cmax, n_bodies_max=Nmax,
                                       nk=self._ops.nk)
         self._build_compute_tables()
-        self._x_pad, self._q_pad = stack_bodies(geometry.trees, Nmax)
+        self._x_pad, self._q_pad = self._shard(stack_bodies(geometry.trees,
+                                                            Nmax))
         self._programs: dict = {}
-        self._fns: dict = {}
         self._ex_fns: dict = {}
 
     # ------------------------------------------------------------- tables --
@@ -278,7 +373,14 @@ class ShardedEngine:
         for bi, b in enumerate(self.p2p_buckets):
             for k, v in b.items():
                 rt[f"pb{bi}_{k}"] = v
-        self._rank_tabs = rt
+        self._part_tabs, self._rank_tabs = self._shard((self._part_tabs, rt))
+
+    def _shard(self, tree):
+        """Upload host arrays once, split on their leading (partition or
+        rank) axis over the mesh, so no evaluation re-uploads them."""
+        from jax.sharding import NamedSharding
+        return jax.device_put(tree, NamedSharding(self.mesh,
+                                                  _rank_spec(self.mesh)))
 
     # ----------------------------------------------------------- programs --
     def program(self, protocol: str) -> prog_mod.ExchangeProgram:
@@ -297,103 +399,32 @@ class ShardedEngine:
         s["rank_bytes"] = self.layout.rank_bytes.tolist()
         return s
 
-    # ------------------------------------------------------------ program --
-    def _shard_fn(self, protocol: str):
-        if protocol in self._fns:
-            return self._fns[protocol]
-        from jax.experimental.shard_map import shard_map
-        from jax.sharding import PartitionSpec as PS
-        from repro.core.engine.upward import batched_upward_kernel
+    def _statics(self) -> dict:
+        return dict(mesh=self.mesh, ops=self._ops,
+                    n_cells=self.up.n_cells_max,
+                    ppr=self.layout.parts_per_rank)
 
-        ops = self._ops
-        program = self.program(protocol)
-        axis = self.axis
-        Cmax = self.up.n_cells_max
-        ppr = self.layout.parts_per_rank
-        nk = ops.nk
-        n_buckets = len(self.p2p_buckets)
-        has_m2l = self.m2l is not None
-        has_m2p = self.m2p is not None
+    def _exchange(self, protocol: str):
+        """(jitted exchange-only program, its round tables) for one protocol,
+        memoized; the compute programs are shared by every protocol."""
+        if protocol not in self._ex_fns:
+            program = self.program(protocol)
+            self._ex_fns[protocol] = (self._build_exchange_fn(program),
+                                      prog_mod.round_tables(program))
+        return self._ex_fns[protocol]
 
-        def rank_fn(x, q, pt, rt, rtabs):
-            # x (ppr, Nmax, 3) f32, q (ppr, Nmax) f32 — this rank's slice
-            M = batched_upward_kernel(
-                ops, x, q, pt["leaves"], pt["leaf_mask"], pt["leaf_centers"],
-                pt["leaf_idx"], pt["leaf_valid"], pt["up_ids"],
-                pt["up_parents"], pt["up_mask"], pt["up_d"], n_cells=Cmax)
-            M_flat = M.reshape(ppr * Cmax, nk)
-            # pack the dynamic words of every originated span, then exchange
-            src_vec = jnp.concatenate([M_flat.reshape(-1), x.reshape(-1),
-                                       q.reshape(-1)])
-            pool = rt["pool_template"][0]
-            pool = pool.at[rt["pack_dst"][0]].set(src_vec[rt["pack_src"][0]])
-            pool = prog_mod.apply_exchange(pool, program, rtabs, axis)
-            M_halo = pool[rt["halo_M_idx"][0]]
-            x_halo = pool[rt["halo_x_idx"][0]]
-            q_halo = pool[rt["halo_q_idx"][0]]
-
-            # far field over [local | halo] sources
-            M_src = jnp.concatenate([M_flat, M_halo], axis=0)
-            L_flat = jnp.zeros((ppr * Cmax, nk), jnp.float32)
-            if has_m2l:
-                contrib = ops.m2l_v(M_src[rt["m2l_src"][0]],
-                                    rt["m2l_d"][0]) \
-                    * rt["m2l_mask"][0][:, None]
-                L_flat = L_flat.at[rt["m2l_tgt"][0]].add(contrib)
-            L = L_flat.reshape(ppr, Cmax, nk)
-
-            def l2l_one(Lp, ids, parents, mask, d):
-                return Lp.at[ids].add(ops.l2l_v(Lp[parents], d)
-                                      * mask[:, None])
-
-            for lvl in range(pt["down_ids"].shape[1]):
-                L = jax.vmap(l2l_one)(L, pt["down_ids"][:, lvl],
-                                      pt["down_parents"][:, lvl],
-                                      pt["down_mask"][:, lvl],
-                                      pt["down_d"][:, lvl])
-
-            def l2p_one(Lp, xp, lf, lm, lc, li):
-                return ops.l2p_v(Lp[lf], xp[li], lc) * lm[:, None]
-
-            outs = [jax.vmap(l2p_one)(L, x, pt["leaves"], pt["leaf_mask"],
-                                      pt["leaf_centers"], pt["leaf_idx"])]
-
-            x_flat = x.reshape(-1, 3)
-            q_flat = q.reshape(-1)
-            x_src = jnp.concatenate([x_flat, x_halo], axis=0)
-            q_src = jnp.concatenate([q_flat, q_halo], axis=0)
-            for bi in range(n_buckets):
-                t_idx = rt[f"pb{bi}_t_idx"][0]
-                s_idx = rt[f"pb{bi}_s_idx"][0]
-                qs = jnp.where(rt[f"pb{bi}_s_valid"][0], q_src[s_idx], 0.0)
-                outs.append(_p2p_vals(x_flat[t_idx], x_src[s_idx], qs,
-                                      rt[f"pb{bi}_mask"][0]))
-            if has_m2p:
-                outs.append(ops.m2p_v(M_src[rt["m2p_b"][0]],
-                                      x_flat[rt["m2p_t_idx"][0]],
-                                      rt["m2p_centers"][0])
-                            * rt["m2p_mask"][0][:, None])
-            return tuple(outs)
-
-        spec = PS(axis)
-        n_outs = 1 + n_buckets + (1 if has_m2p else 0)
-        fn = jax.jit(shard_map(
-            rank_fn, mesh=self.mesh, in_specs=(spec,) * 5,
-            out_specs=(spec,) * n_outs, check_rep=False))
-        self._fns[protocol] = fn
-        return fn
-
-    # ----------------------------------------------------------- evaluate --
     def evaluate(self, protocol: str = "bulk") -> np.ndarray:
         """Full potential in original body order (float64, host) — the
         rank-sharded phases run under `shard_map`, phi accumulates in host
         f64 exactly like `DeviceEngine.evaluate`'s non-x64 path."""
         with obs.span("dist.evaluate") as sp:
-            fn = self._shard_fn(protocol)
-            outs = sp.fence(fn(self._x_pad, self._q_pad, self._part_tabs,
-                               self._rank_tabs,
-                               prog_mod.round_tables(
-                                   self.program(protocol))))
+            statics = self._statics()
+            x, q, pt, rt = self._x_pad, self._q_pad, self._part_tabs, \
+                self._rank_tabs
+            M_flat, pool = _pack(x, q, pt, rt, **statics)
+            ex, rtabs = self._exchange(protocol)
+            outs = sp.fence(_far(x, q, M_flat, ex(pool, rtabs), pt, rt,
+                                 n_buckets=len(self.p2p_buckets), **statics))
             obs.counter_add("dist.evaluations")
             if obs.enabled():
                 sp.set({"protocol": protocol, "n_ranks": self.n_ranks})
@@ -425,8 +456,8 @@ class ShardedEngine:
         needed here."""
         from repro.core.engine.schedules import stack_bodies
         self.geo = geometry
-        self._x_pad, self._q_pad = stack_bodies(geometry.trees,
-                                                self.up.n_bodies_max)
+        self._x_pad, self._q_pad = self._shard(
+            stack_bodies(geometry.trees, self.up.n_bodies_max))
 
     # ------------------------------------------------------- verification --
     def verify_exchange(self, protocol: str = "bulk") -> int:
@@ -440,39 +471,13 @@ class ShardedEngine:
         failure and fall back to the single-device engine); returns the
         number of verified spans.  Triggered by `REPRO_VERIFY_EXCHANGE=1`
         once per (protocol, geometry version) via the session."""
-        from jax.experimental.shard_map import shard_map
-        from jax.sharding import PartitionSpec as PS
-        from repro.core.engine.upward import batched_upward_kernel
         from repro.resilience.fallback import ExchangeVerificationError
 
-        ops = self._ops
-        program = self.program(protocol)
-        axis = self.axis
-        Cmax = self.up.n_cells_max
-        ppr = self.layout.parts_per_rank
-        nk = ops.nk
-
-        def rank_verify(x, q, pt, rt, rtabs):
-            M = batched_upward_kernel(
-                ops, x, q, pt["leaves"], pt["leaf_mask"], pt["leaf_centers"],
-                pt["leaf_idx"], pt["leaf_valid"], pt["up_ids"],
-                pt["up_parents"], pt["up_mask"], pt["up_d"], n_cells=Cmax)
-            M_flat = M.reshape(ppr * Cmax, nk)
-            src_vec = jnp.concatenate([M_flat.reshape(-1), x.reshape(-1),
-                                       q.reshape(-1)])
-            pool = rt["pool_template"][0]
-            packed = pool.at[rt["pack_dst"][0]].set(src_vec[rt["pack_src"][0]])
-            exchanged = prog_mod.apply_exchange(packed, program, rtabs, axis)
-            return packed[None], exchanged[None]
-
-        spec = PS(axis)
-        fn = jax.jit(shard_map(
-            rank_verify, mesh=self.mesh, in_specs=(spec,) * 5,
-            out_specs=(spec, spec), check_rep=False))
         with obs.span("dist.verify_exchange"):
-            packed, exchanged = fn(self._x_pad, self._q_pad, self._part_tabs,
-                                   self._rank_tabs,
-                                   prog_mod.round_tables(program))
+            _, packed = _pack(self._x_pad, self._q_pad, self._part_tabs,
+                              self._rank_tabs, **self._statics())
+            ex, rtabs = self._exchange(protocol)
+            exchanged = ex(packed, rtabs)
         packed = np.asarray(packed)
         exchanged = np.asarray(exchanged)
         lay = self.layout
@@ -493,35 +498,32 @@ class ShardedEngine:
 
     # ---------------------------------------------------------- benchmark --
     def _build_exchange_fn(self, program: prog_mod.ExchangeProgram):
-        """Jitted shard_map program running ONLY pack + exchange (no FMM
-        phases) for an arbitrary `ExchangeProgram` — including single-round
-        sub-programs, which is how `measure_exchange(per_round=True)` times
-        each collective round in isolation.  Returns `fn()` -> (D,) per-rank
-        pool checksums (the reduction defeats dead-code elimination)."""
-        from jax.experimental.shard_map import shard_map
+        """Jitted shard_map program running ONLY one `ExchangeProgram` over
+        the rank pools: `fn(pool (D, W), round_tables) -> pool`.  Evaluation
+        runs it between the shared pack and far-field programs; the
+        benchmarks time it alone, including single-round sub-programs
+        (`measure_exchange(per_round=True)`)."""
         from jax.sharding import PartitionSpec as PS
         axis = self.axis
 
-        def rank_ex(rt, rtabs):
-            pool = rt["pool_template"][0]
-            pool = prog_mod.apply_exchange(pool, program, rtabs, axis)
-            return jnp.sum(pool)[None]
+        def rank_ex(pool, rtabs):
+            return prog_mod.apply_exchange(pool[0], program, rtabs,
+                                           axis)[None]
 
-        fn = jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             rank_ex, mesh=self.mesh, in_specs=(PS(axis),) * 2,
-            out_specs=PS(axis), check_rep=False))
-        tabs = {"pool_template": self.wire.pool_template}
-        rtabs = prog_mod.round_tables(program)
-        return lambda: fn(tabs, rtabs)
+            out_specs=PS(axis), check_vma=False))
 
     def exchange_fn(self, protocol: str):
-        """Memoized `_build_exchange_fn` for one protocol's full program —
-        what `benchmarks/fig8_exchange.py` times against the LogGP
-        prediction."""
-        if protocol not in self._ex_fns:
-            self._ex_fns[protocol] = self._build_exchange_fn(
-                self.program(protocol))
-        return self._ex_fns[protocol]
+        """`fn()` running one protocol's exchange program on the wire
+        template alone (no FMM phases) — what `benchmarks/fig8_exchange.py`
+        times against the LogGP prediction."""
+        ex, rtabs = self._exchange(protocol)
+        return self._template_runner(ex, rtabs)
+
+    def _template_runner(self, ex, rtabs):
+        pool = jnp.asarray(self.wire.pool_template)
+        return lambda: ex(pool, rtabs)
 
     def measure_exchange(self, protocol: str, *, reps: int = 3,
                          per_round: bool = False) -> dict:
@@ -551,7 +553,8 @@ class ShardedEngine:
         if per_round:
             for rnd, rec in zip(p.rounds, rounds):
                 sub = _dc.replace(p, rounds=(rnd,))
-                sub_fn = self._build_exchange_fn(sub)
+                sub_fn = self._template_runner(self._build_exchange_fn(sub),
+                                               prog_mod.round_tables(sub))
                 jax.block_until_ready(sub_fn())
                 rt0 = _time.perf_counter()
                 for _ in range(reps):

@@ -53,7 +53,8 @@ from repro.core.engine.schedules import (BatchedUpwardSchedule, EngineTables,
                                          build_engine_tables,
                                          build_p2p_stream_tables,
                                          shape_class_digest, stack_bodies,
-                                         stack_reference_bodies)
+                                         stack_reference_bodies,
+                                         stream_out_index)
 from repro.core.engine.traversal import (default_traversal_backend,
                                          device_dual_traversal,
                                          partition_drift,
@@ -154,6 +155,9 @@ class DeviceEngine:
                            else bool(p2p_stream))
         self._stream = None          # unified stream tables, built lazily
         self._stream_params = None   # autotuned (block_t, n_buffers)
+        self._stream_out_tabs = None  # host per-lane stream output slots
+        self._stream_candidates = {}  # block_t -> tables, autotune sweep
+        self._stream_warm = set()     # (block_t, n_buffers) compiled+warmed
         self.exe_cache = resolve_cache(exe_cache)
         self._entries: dict = {}     # (kind, x64) -> (CompiledEntry, tabs)
         self.launch_log: list = []   # (kind, key) per fused dispatch
@@ -229,18 +233,24 @@ class DeviceEngine:
     # ---------------------------------------------------------- streaming --
     def _measure_stream(self, block_t: int, n_buffers: int) -> float:
         """Time one streaming launch at candidate (block_t, n_buffers) —
-        the `best_stream_params` measure closure on real backends (tables
-        are rebuilt per block_t because the tiling depends on it)."""
+        the `best_stream_params` measure closure on real backends (the
+        tiling depends on block_t, so tables are built once per block_t)."""
         import time
-        stream = build_p2p_stream_tables(self.tables.p2p_buckets, block_t)
+        stream = self._stream_candidates.get(block_t)
         if stream is None:
-            return float("inf")
+            stream = build_p2p_stream_tables(self.tables.p2p_buckets,
+                                             block_t)
+            if stream is None:
+                return float("inf")
+            self._stream_candidates[block_t] = stream
         aa = self._aa
         fn = lambda: p2p_stream_vals(
             aa(self._x_pad, jnp.float32), aa(self._q_pad, jnp.float32),
             stream, use_kernels=True, interpret=self.interpret,
             asarray=self.memo, n_buffers=n_buffers)
-        jax.block_until_ready(fn())          # compile + warm
+        if (block_t, n_buffers) not in self._stream_warm:
+            jax.block_until_ready(fn())      # compile + warm, once
+            self._stream_warm.add((block_t, n_buffers))
         t0 = time.perf_counter()
         jax.block_until_ready(fn())
         return time.perf_counter() - t0
@@ -249,8 +259,9 @@ class DeviceEngine:
         """Resolve the unified stream tables for this geometry (lazily, once):
         autotune (block_t, n_buffers) for the stream shape class, build the
         tile table, and VERIFY the contiguous-run invariant — returns None
-        (and flips the engine back to gathered buckets, counted at
-        `p2p.stream.fallbacks`) when the invariant does not hold."""
+        (and flips the engine back to gathered buckets, with a
+        RuntimeWarning and the `p2p.stream.fallbacks` counter) when the
+        invariant does not hold."""
         if not self.p2p_stream:
             return None
         if self._stream is not None:
@@ -270,9 +281,19 @@ class DeviceEngine:
                    if self.use_kernels and not interp else None)
         bt, nb = best_stream_params(smax, n_rows, wt_max,
                                     interpret=interp, measure=measure)
-        stream = build_p2p_stream_tables(t.p2p_buckets, bt)
+        stream = self._stream_candidates.get(bt)
+        self._stream_candidates = {}     # drop the sweep's other tilings
+        self._stream_warm = set()
+        if stream is None:
+            stream = build_p2p_stream_tables(t.p2p_buckets, bt)
         if stream is None:
             obs.counter_add("p2p.stream.fallbacks")
+            import warnings
+            warnings.warn(
+                "streaming P2P is off for this geometry: its near-field rows "
+                "are not contiguous runs of bodies, so the gathered buckets "
+                "run instead (counted at p2p.stream.fallbacks)",
+                RuntimeWarning, stacklevel=3)
             self.p2p_stream = False
             return None
         self._stream = stream
@@ -285,6 +306,16 @@ class DeviceEngine:
                        "smax": stream["smax"], "block_t": bt,
                        "n_buffers": nb, "n_buckets": len(t.p2p_buckets)})
         return stream
+
+    def _stream_out(self):
+        """Host per-lane (out_idx, out_valid) of the stream tables, built
+        once for the per-phase accumulation (the fused program derives them
+        in-trace instead)."""
+        if self._stream_out_tabs is None:
+            s = self._stream
+            self._stream_out_tabs = stream_out_index(
+                s["meta"], s["tgt_range"], s["block_t"])
+        return self._stream_out_tabs
 
     def _fused_entry(self, kind: str):
         """Resolve this engine's fused executable + uploaded tables for
@@ -457,7 +488,7 @@ class DeviceEngine:
                 # two slab DMAs (sources + targets) per live tile
                 obs.counter_add("p2p.stream.dma_tiles",
                                 2 * stream["n_live_tiles"])
-            yield stream["out_idx"], stream["out_valid"], vals
+            yield (*self._stream_out(), vals)
         else:
             for bucket in t.p2p_buckets:
                 with obs.span("engine.p2p_bucket") as sp:

@@ -51,6 +51,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from repro.core.engine.m2l import far_tail_kernel, m2p_vals_kernel
+from repro.core.engine.schedules import stream_out_index
 from repro.core.engine.traversal import _drift_changed_kernel, _restack_kernel
 from repro.core.engine.upward import batched_upward_kernel
 from repro.core.fmm import _p2p_vals
@@ -81,8 +82,7 @@ def flatten_eval_tables(tables, stream: dict | None = None) -> dict:
         flat[f"m2p_{k}"] = v
     if stream is not None:
         flat["p2ps_meta"] = stream["meta"]
-        flat["p2ps_out_idx"] = stream["out_idx"]
-        flat["p2ps_out_valid"] = stream["out_valid"]
+        flat["p2ps_tgt_range"] = stream["tgt_range"]
     else:
         for i, b in enumerate(tables.p2p_buckets):
             for k, v in b.items():
@@ -188,8 +188,10 @@ def build_fused_evaluate(ops, tables, *, use_kernels: bool,
                 vals = p2p_stream_gathered(tab["p2ps_meta"], payload,
                                            block_t=stream["block_t"],
                                            smax=stream["smax"])
-            phi_flat = add(phi_flat, tab["p2ps_out_idx"],
-                           tab["p2ps_out_valid"], vals)
+            out_idx, out_valid = stream_out_index(
+                tab["p2ps_meta"], tab["p2ps_tgt_range"], stream["block_t"],
+                xp=jnp)
+            phi_flat = add(phi_flat, out_idx, out_valid, vals)
         else:
             x_flat = x_pad.reshape(-1, 3)
             q_flat = q_pad.reshape(-1)

@@ -30,7 +30,12 @@ from repro.core.fmm import _p2p_vals, device_hook
 from repro.resilience import faults as _faults
 
 __all__ = ["p2p_bucket_vals", "p2p_stream_vals", "p2p_stream_gathered",
-           "stream_payload"]
+           "p2p_rows_vals", "stream_payload", "P2P_ROW_CHUNK"]
+
+# gathered pair rows per step of `p2p_rows_vals`: the jnp direct sum holds a
+# (rows, wt, ws) distance tensor, which a deployment's million rows could
+# not hold at once
+P2P_ROW_CHUNK = 1 << 12
 
 
 @jax.jit
@@ -42,6 +47,32 @@ def _gather_bucket(x, q, t_idx, s_idx, s_valid):
     xs = x_flat[s_idx]                            # (B, ws, 3)
     qs = jnp.where(s_valid, q_flat[s_idx], 0.0)   # (B, ws)
     return xt, xs, qs
+
+
+def p2p_rows_vals(x_tgt, x_src, q_src, t_idx, s_idx, s_valid, mask):
+    """jnp direct sum over gathered pair rows -> (B, wt) masked values:
+    targets `x_tgt[t_idx]`, sources `x_src[s_idx]` with charges `q_src`
+    (zeroed where `s_valid` is False).  Runs `P2P_ROW_CHUNK` rows at a
+    time; tables of at most one chunk take the one-shot path.  Traceable."""
+    chunk = P2P_ROW_CHUNK
+    def rows(t, s, sv, m):
+        qs = jnp.where(sv, q_src[s], 0.0)
+        return _p2p_vals(x_tgt[t], x_src[s], qs, m)
+
+    n = t_idx.shape[0]
+    if n <= chunk:
+        return rows(t_idx, s_idx, s_valid, mask)
+    pad = (-n) % chunk
+    k = (n + pad) // chunk
+
+    def split(a):
+        a = jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))
+        return a.reshape((k, chunk) + a.shape[1:])
+
+    out = jax.lax.map(lambda a: rows(*a),
+                      (split(t_idx), split(s_idx), split(s_valid),
+                       split(mask)))
+    return out.reshape(k * chunk, -1)[:n]
 
 
 def p2p_bucket_vals(x, q, bucket, use_kernels: bool = False,
@@ -67,39 +98,42 @@ def p2p_bucket_vals(x, q, bucket, use_kernels: bool = False,
 
 def stream_payload(x, q, pad: int):
     """Flatten the (P, Nmax, ...) payload into the streaming kernel's
-    structure-of-arrays slab source: (4, P*Nmax + pad) f32 rows [x; y; z; q],
-    zero-padded so fixed-size slab reads never run past the end.  Traceable —
-    the fused program builds it in-trace from the donated payload (one
-    transpose pass instead of one gather per bucket)."""
+    structure-of-arrays slab source: (4, F) f32 rows [x; y; z; q] with at
+    least `pad` zero lanes past the last body and F rounded up to the
+    128-lane HBM tile.  Traceable — the fused program builds it in-trace
+    from the donated payload (one transpose pass instead of one gather per
+    bucket)."""
+    from repro.kernels.p2p_stream import LANE
     x_flat = x.reshape(-1, 3).astype(jnp.float32)
     q_flat = q.reshape(-1).astype(jnp.float32)
     soa = jnp.concatenate([x_flat.T, q_flat[None, :]], axis=0)
-    return jnp.pad(soa, ((0, 0), (0, pad)))
+    n = soa.shape[1]
+    return jnp.pad(soa, ((0, 0), (0, pad + (-(n + pad)) % LANE)))
 
 
 def p2p_stream_gathered(meta, payload, *, block_t: int, smax: int):
-    """XLA reference for the streaming kernel: gather the SAME (4, smax) /
+    """XLA reference for the streaming kernel: gather the SAME (4, ws) /
     (4, block_t) slabs the kernel DMAs, run the SAME tile expression
     (`stream_tile_phi`).  This is the `use_kernels=False` streaming path —
     on CPU it beats interpret-mode kernel emulation by orders of magnitude
     while keeping the unified one-program-all-width-classes structure."""
-    from repro.kernels.p2p_stream import stream_tile_phi
-    lane_s = jnp.arange(smax)
+    from repro.kernels.p2p_stream import src_width, stream_tile_phi
+    meta = meta.reshape(-1, 4)
+    lane_s = jnp.arange(src_width(smax))
     lane_t = jnp.arange(block_t)
-    src = payload[:, meta[:, 0:1] + lane_s[None, :]]    # (4, Ti, smax)
-    tgt = payload[:, meta[:, 2:3] + lane_t[None, :]]    # (4, Ti, block_t)
-    phi = jax.vmap(stream_tile_phi, in_axes=(1, 1, 0))(
-        src, tgt, meta[:, 1])
-    return jnp.where((meta[:, 3] > 0)[:, None], phi, 0.0)
+    src = payload[:, meta[:, 0:1] + lane_s[None, :]]    # (4, Ti, ws)
+    tgt = payload[:, meta[:, 3:4] + lane_t[None, :]]    # (4, Ti, block_t)
+    phi = jax.vmap(stream_tile_phi, in_axes=(1, 1, 0, 0))(
+        src, tgt, meta[:, 1], meta[:, 2])
+    return jnp.where((meta[:, 2] > meta[:, 1])[:, None], phi, 0.0)
 
 
 def p2p_stream_vals(x, q, stream: dict, *, use_kernels: bool,
                     interpret: bool | None = None, asarray=None,
                     n_buffers: int = 2):
     """Evaluate the unified stream table -> (Ti, block_t) f32 device values
-    (mask semantics live in the table's `out_valid`; lanes past a tile's
-    target count are garbage exactly as in the gathered kernel and must be
-    dropped by the caller's accumulation)."""
+    (which lanes are real targets is `schedules.stream_out_index`; the
+    others hold finite values the caller's accumulation must drop)."""
     aa = device_hook(asarray)
     payload = stream_payload(x, q, stream["pad"])
     meta = aa(stream["meta"])

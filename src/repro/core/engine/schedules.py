@@ -46,7 +46,7 @@ from repro.resilience import faults as _faults
 
 __all__ = ["BatchedUpwardSchedule", "EngineTables", "build_batched_upward",
            "build_engine_tables", "build_p2p_stream_tables", "stack_bodies",
-           "stack_reference_bodies", "shape_class_digest"]
+           "stack_reference_bodies", "shape_class_digest", "stream_out_index"]
 
 
 def shape_class_digest(tables: dict) -> str:
@@ -228,27 +228,40 @@ def build_p2p_stream_tables(p2p_buckets, block_t: int) -> dict | None:
     runs), so a row reduces to `(start, length)` — one slab DMA instead of a
     per-element gather.  This builder VERIFIES that invariant row by row and
     returns None when any row violates it (the engine then falls back to the
-    gathered buckets for that geometry — correctness never depends on the
-    fast path).
+    gathered buckets for that geometry, counted at `p2p.stream.fallbacks`).
+
+    The payload's body axis is tiled by 128 lanes in HBM, so a slab DMA
+    starts at a 128-aligned flat id and moves a 128-multiple of lanes.  A
+    row's sources `[s0, s0 + len)` sit at in-slab lanes `[s0 % 128, s0 %
+    128 + len)` of a `src_width(smax)` slab starting at `s0 - s0 % 128`.
+    Its targets are covered by 128-aligned windows of `block_t` lanes
+    starting at `t0 - t0 % 128`: one tile per window, so a row spans
+    `ceil((t0 % 128 + len) / block_t)` tiles.
 
     Returns a dict of frozen tables (payload-independent, device-memoizable):
 
-      meta     (Ti, 4) int32 — per-tile [src_start, src_len, tgt_start,
-               tgt_len]; dead padding tiles carry tgt_len == 0 and are
-               pruned inside the kernel (no DMA, no compute).
-      out_idx  (Ti, block_t) int64 — flat output slot per target lane
-               (dead lanes point at slot 0).
-      out_valid (Ti, block_t) bool — lane < tgt_len.
+      meta      (4 * Ti,) int32 — per tile [src_base, src_lo, src_hi,
+                tgt_base], flattened tile-major (kernels.p2p_stream
+                layout); dead padding tiles are all zero and are pruned
+                inside the kernel.
+      tgt_range (2, Ti) int32 — the flat target ids `[lo, hi)` the tile's
+                lanes may write; `stream_out_index` turns it into the
+                per-lane output slots and validity.
 
-    plus statics: smax (power-of-two max source width, the slab size),
-    block_t, n_tiles (== Ti, padded to a bucket_size envelope so geometries
-    of one shape class share one compiled program), n_live_tiles, and pad
-    (payload zero-padding rows so fixed-size slab DMAs never read past the
-    end: max(smax, block_t))."""
+    plus statics: smax (power-of-two max source width), block_t, n_tiles
+    (== Ti, padded to a bucket_size envelope so geometries of one shape
+    class share one compiled program), n_live_tiles, and pad (payload
+    zero-padding lanes so every slab read stays in bounds: the widest
+    slab).  Both tables are lane-dense: a (Ti, 4) or (Ti, 2) int32 array
+    would pad its last dim to 128 lanes in device memory."""
+    from repro.kernels.p2p_stream import LANE, src_width
     _faults.fire("p2p.stream.tables")
     if not p2p_buckets:
         return None
-    metas = []
+    if block_t % LANE:
+        raise ValueError(f"block_t must be a multiple of {LANE}, "
+                         f"got {block_t}")
+    metas, ranges = [], []
     smax = 8
     for b in p2p_buckets:
         sv, tv = b["s_valid"], b["t_valid"]
@@ -272,36 +285,48 @@ def build_p2p_stream_tables(p2p_buckets, block_t: int) -> dict | None:
         if not ok:
             return None
         smax = max(smax, ws)
-        s0 = b["s_idx"][live, 0]
-        t0 = b["t_idx"][live, 0]
-        sl, tl = s_len[live], t_len[live]
-        # tile each row's targets into block_t-lane tiles
-        n_t = np.maximum((tl + block_t - 1) // block_t, 1)
+        # rows with no sources or no targets contribute exactly 0
+        keep = live & (s_len > 0) & (t_len > 0)
+        s0 = b["s_idx"][keep, 0].astype(np.int64)
+        t0 = b["t_idx"][keep, 0].astype(np.int64)
+        sl, tl = s_len[keep], t_len[keep]
+        s_off, t_off = s0 % LANE, t0 % LANE
+        # one tile per block_t-lane window over the row's aligned targets
+        n_t = (t_off + tl + block_t - 1) // block_t
         rep = np.repeat(np.arange(len(tl)), n_t)
         k = np.arange(len(rep)) - np.repeat(np.cumsum(n_t) - n_t, n_t)
         metas.append(np.stack([
-            s0[rep], sl[rep],
-            t0[rep] + k * block_t,
-            np.minimum(block_t, tl[rep] - k * block_t)], axis=1))
-    meta = (np.concatenate(metas, axis=0) if metas
-            else np.zeros((0, 4), np.int64))
-    meta = meta[meta[:, 3] > 0]      # rows with zero targets contribute 0
+            (s0 - s_off)[rep], s_off[rep], (s_off + sl)[rep],
+            (t0 - t_off)[rep] + k * block_t], axis=1))
+        ranges.append(np.stack([t0[rep], (t0 + tl)[rep]], axis=1))
+    meta = np.concatenate(metas, axis=0)
     n_live = len(meta)
     if n_live == 0:
         return None
-    ti = bucket_size(n_live)
-    meta = np.concatenate(
-        [meta, np.zeros((ti - n_live, 4), np.int64)], axis=0)
-    if int(meta.max()) + max(smax, block_t) >= np.iinfo(np.int32).max:
+    pad = max(src_width(smax), block_t)
+    if int(meta.max()) + pad >= np.iinfo(np.int32).max:
         return None                  # flat ids must survive int32 meta
-    lane = np.arange(block_t, dtype=np.int64)
-    out_valid = lane[None, :] < meta[:, 3:4]
-    out_idx = np.where(out_valid, meta[:, 2:3] + lane[None, :], 0)
-    return {"meta": meta.astype(np.int32), "out_idx": out_idx,
-            "out_valid": out_valid, "smax": int(smax),
+    ti = bucket_size(n_live)
+    meta = np.concatenate([meta, np.zeros((ti - n_live, 4), np.int64)])
+    trange = np.concatenate([np.concatenate(ranges, axis=0),
+                             np.zeros((ti - n_live, 2), np.int64)])
+    return {"meta": meta.astype(np.int32).reshape(-1),
+            "tgt_range": np.ascontiguousarray(trange.T.astype(np.int32)),
+            "smax": int(smax),
             "block_t": int(block_t), "n_tiles": int(ti),
-            "n_live_tiles": int(n_live),
-            "pad": int(max(smax, block_t))}
+            "n_live_tiles": int(n_live), "pad": int(pad)}
+
+
+def stream_out_index(meta, tgt_range, block_t: int, xp=np):
+    """Per-lane output slots of a stream table: lane j of tile i is flat
+    body `tgt_base[i] + j`, valid iff it lies in `[lo, hi)` of
+    `tgt_range[:, i]`.  Returns (out_idx (Ti, block_t), out_valid (Ti,
+    block_t) bool), invalid lanes at slot 0.  `xp` is `np` on the host or
+    `jnp` inside a traced program, so the per-lane tables never have to be
+    stored."""
+    gid = meta[3::4][:, None] + xp.arange(block_t, dtype=meta.dtype)[None, :]
+    valid = (gid >= tgt_range[0][:, None]) & (gid < tgt_range[1][:, None])
+    return xp.where(valid, gid, 0), valid
 
 
 def build_engine_tables(geo) -> EngineTables:

@@ -263,14 +263,17 @@ def device_dual_traversal(tgt_tree, src_tree, theta: float = 0.5,
         raise RuntimeError(f"device traversal overflowed after "
                            f"{max_retries} capacity doublings")
 
-    def pairs(a, b, n):
-        n = int(n)
-        return np.stack([np.asarray(a[:n], np.int64),
-                         np.asarray(b[:n], np.int64)], axis=1)
+    # one transfer, then slice on the host: a device slice per count would
+    # compile a program for every distinct length
+    out = jax.device_get({k: v for k, v in out.items()
+                          if k not in ("A", "B")})
 
-    m2l = pairs(out["m2l_a"], out["m2l_b"], out["n_m2l"])
-    p2p = pairs(out["p2p_a"], out["p2p_b"], out["n_p2p"])
-    m2p = pairs(out["m2p_a"], out["m2p_b"], out["n_m2p"])
+    def pairs(kind):
+        n = int(out[f"n_{kind}"])
+        return np.stack([out[f"{kind}_a"][:n], out[f"{kind}_b"][:n]],
+                        axis=1).astype(np.int64)
+
+    m2l, p2p, m2p = pairs("m2l"), pairs("p2p"), pairs("m2p")
     if not with_m2p and len(m2p):
         raise AssertionError("truncated source cells require with_m2p=True")
     return m2l, p2p, m2p, float(out["min_margin"])

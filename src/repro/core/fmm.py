@@ -92,10 +92,13 @@ def direct_potential(x, q, x_tgt=None, chunk: int = 2048) -> np.ndarray:
     x = np.asarray(x, dtype=np.float64)
     q = np.asarray(q, dtype=np.float64)
     xt = x if x_tgt is None else np.asarray(x_tgt, dtype=np.float64)
+    cols = [np.ascontiguousarray(x[:, k]) for k in range(3)]
     out = np.zeros(len(xt))
     for s in range(0, len(xt), chunk):
-        d = xt[s:s + chunk, None, :] - x[None, :, :]
-        r2 = (d ** 2).sum(-1)
+        t = xt[s:s + chunk]
+        r2 = (t[:, 0:1] - cols[0]) ** 2       # same order as summing d**2
+        r2 += (t[:, 1:2] - cols[1]) ** 2      # over the last axis, without
+        r2 += (t[:, 2:3] - cols[2]) ** 2      # a (chunk, N, 3) temporary
         inv = np.where(r2 > 0, 1.0 / np.sqrt(np.maximum(r2, 1e-300)), 0.0)
         out[s:s + chunk] = inv @ q
     return out

@@ -118,6 +118,37 @@ def _deriv_fns(max_order: int):
     return tuple(fns)
 
 
+def _powers(d, m: int):
+    """(3, m+1) table of d_i^j, j = 0..m, by repeated multiplication.
+
+    A float `d ** j` lowers to a pow whose last bits depend on how XLA
+    vectorizes the surrounding loop, so the same expression traced at two
+    batch shapes (the per-tree and the batched upward pass) disagreed by a
+    few ulps; products are correctly rounded wherever they run."""
+    cols = [jnp.ones_like(d)]
+    for _ in range(m):
+        cols.append(cols[-1] * d)
+    return jnp.stack(cols, axis=1)
+
+
+def _pairwise_sum(a):
+    """Sum over axis 0 as a fixed halving tree over the next power of two.
+
+    `a.sum(axis=0)` leaves the association to XLA, which picks it per
+    shape, so a leaf summed inside the batched (partitions x leaves) pass
+    and the same leaf in the per-tree pass came out a few ulps apart.  With
+    zero rows appended, any two widths whose nonzero rows share a prefix
+    add in the same order."""
+    n = a.shape[0]
+    w = 1 << max(n - 1, 0).bit_length()
+    if w != n:
+        a = jnp.concatenate([a, jnp.zeros((w - n,) + a.shape[1:], a.dtype)])
+    while a.shape[0] > 1:
+        h = a.shape[0] // 2
+        a = a[:h] + a[h:]
+    return a[0]
+
+
 class MultipoleOperators:
     """Order-p Cartesian Taylor operators; all methods map over leading dims."""
 
@@ -141,12 +172,12 @@ class MultipoleOperators:
     # ---- building blocks -------------------------------------------------
     def _monomials_ext(self, d):
         """d^k for every extended multi-index k. d: (3,) -> (ne,)."""
-        pows = d[:, None] ** jnp.arange(self._max_order + 1, dtype=d.dtype)  # (3, max+1)
+        pows = _powers(d, self._max_order)                       # (3, max+1)
         return pows[0, self._E[:, 0]] * pows[1, self._E[:, 1]] * pows[2, self._E[:, 2]]
 
     def _monomials_k(self, d):
         K = self._K
-        pows = d[:, None] ** jnp.arange(self.p, dtype=d.dtype)
+        pows = _powers(d, self.p - 1)
         return pows[0, K[:, 0]] * pows[1, K[:, 1]] * pows[2, K[:, 2]]
 
     def derivs(self, d):
@@ -163,7 +194,7 @@ class MultipoleOperators:
     def p2m(self, q, x, center):
         """q: (n,), x: (n,3), center: (3,) -> (nk,). Padded bodies: q=0."""
         mono = jax.vmap(self._monomials_k)(x - center[None, :])   # (n, nk)
-        return (q[:, None] * mono).sum(axis=0) * self._inv_fact_K
+        return _pairwise_sum(q[:, None] * mono) * self._inv_fact_K
 
     def m2m(self, M, d):
         """Translate multipole by d = c_child - c_parent."""

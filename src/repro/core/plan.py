@@ -104,8 +104,9 @@ width-class bucket's `(pairs, S, 3)`/`(pairs, S)` operands via XLA gathers
 before its launch — robust to any index pattern, but one HBM round-trip per
 bucket.  The *streaming* path (`engine/schedules.build_p2p_stream_tables` +
 `kernels/p2p_stream`) concatenates ALL width classes into one unified tile
-table `[src_start, src_len, tgt_start, tgt_len]` and runs one grid that
-gathers source/target slabs inside the kernel as double-buffered VMEM DMAs.
+table `[src_base, src_lo, src_hi, tgt_base]` (slab starts 128-aligned, as
+HBM DMAs require) and runs one grid that gathers source/target slabs
+inside the kernel as double-buffered VMEM DMAs.
 It is only legal because this module's gather tables make every bucket
 row's flat ids a contiguous run (`padded_body_gather` emits
 `body_start + arange`, and the engine's LET translation preserves per-leaf
@@ -115,10 +116,10 @@ never depends on the fast path.  Selection: `FMMSession(p2p_stream=...)`,
 default on iff the backend is TPU (`engine.default_p2p_stream`); with
 `use_kernels=False` the same unified table runs as one XLA slab program
 (`p2p_stream_gathered`), the CPU/CI route.  VMEM budget: scratch is
-`n_buffers * 4 * (smax + block_t)` f32s (SoA [x;y;z;q] source + target
-slabs), and the `(block_t, n_buffers)` autotune (`kernels.p2p
-.best_stream_params`) shrinks block_t until two buffers fit ~1 MB, keeping
-double buffering resident alongside the accumulator tile.
+`n_buffers * 4 * (src_width(smax) + block_t)` f32s (SoA [x;y;z;q] source +
+target slabs); on real backends the `(block_t, n_buffers)` autotune
+(`kernels.p2p.best_stream_params`) times every candidate, and
+`tests/test_tpu_compile.py` compiles each one for a v5e.
 
 A plan is built once and executed many times — time-stepped N-body where
 geometry changes slowly, or protocol sweeps over the same partitioning —

@@ -33,12 +33,8 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *, scale, causal, window, seq_len):
 
     def body(i, carry):
         acc, m_i, l_i = carry
-        # leading dims via dslice, not bare ints: older pallas can't mix int
-        # and Slice indices in one pl.load tuple
-        k = pl.load(k_ref, (pl.dslice(0, 1), pl.dslice(0, 1),
-                            pl.dslice(i * BK, BK), slice(None)))[0, 0]  # (BK, D)
-        v = pl.load(v_ref, (pl.dslice(0, 1), pl.dslice(0, 1),
-                            pl.dslice(i * BK, BK), slice(None)))[0, 0]
+        k = k_ref[0, 0, pl.ds(i * BK, BK), :]                          # (BK, D)
+        v = v_ref[0, 0, pl.ds(i * BK, BK), :]
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)        # (BQ, BK)
         k_pos = i * BK + jax.lax.broadcasted_iota(jnp.int32, (BQ, BK), 1)
         mask = k_pos < seq_len                                          # pad mask

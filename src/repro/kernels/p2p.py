@@ -232,16 +232,24 @@ def effective_block_t(T: int, block_t: int) -> int:
     return max(128, min(block_t, ((T + 127) // 128) * 128))
 
 
+PAIR_GROUP = 8                  # pairs per grid step: one sublane tile
+
+
 def _p2p_kernel(q_ref, xs_ref, xt_ref, out_ref, *, t_total, block_t):
-    # blocks: q (1, S); xs (1, 3, S); xt (1, 3, block_t); out (1, block_t)
-    phi = _tile_phi(q_ref[0], xs_ref[0], xt_ref[0])
+    # blocks of PAIR_GROUP pairs: q (G, S); xs (G, 3, S); xt (G, 3, block_t);
+    # out (G, block_t).  Mosaic requires the last two block dims to be
+    # (8, 128)-divisible or whole, so pairs travel in sublane-sized groups
+    # and each pair's tile is computed on its own.
     if t_total % block_t:
         # partial tail tile: zero the padded lanes (cheap VPU select) so
         # padded targets return 0 instead of garbage
         lane = (pl.program_id(1) * block_t
                 + jax.lax.broadcasted_iota(jnp.int32, (1, block_t), 1)[0])
-        phi = jnp.where(lane < t_total, phi, 0.0)
-    out_ref[0] = phi
+    for k in range(PAIR_GROUP):
+        phi = _tile_phi(q_ref[k], xs_ref[k], xt_ref[k])
+        if t_total % block_t:
+            phi = jnp.where(lane < t_total, phi, 0.0)
+        out_ref[k:k + 1, :] = phi[None, :]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_t"))
@@ -263,25 +271,28 @@ def p2p_pallas(q, x_src, x_tgt, *, interpret: bool = True,
     T = x_tgt.shape[1]
     block_t = effective_block_t(T, block_t)
     pad_t = (-T) % block_t
-    xt = jnp.pad(x_tgt, ((0, 0), (0, pad_t), (0, 0)))
-    Tp = T + pad_t
+    pad_p = (-P) % PAIR_GROUP            # padded pairs: q = 0, sliced off
+    xt = jnp.pad(x_tgt, ((0, pad_p), (0, pad_t), (0, 0)))
+    Tp, Pp = T + pad_t, P + pad_p
     # structure-of-arrays for lane-friendly broadcast
-    xs_t = jnp.swapaxes(x_src, 1, 2)     # (P, 3, S)
-    xt_t = jnp.swapaxes(xt, 1, 2)        # (P, 3, Tp)
+    xs_t = jnp.swapaxes(jnp.pad(x_src, ((0, pad_p), (0, 0), (0, 0))),
+                        1, 2)            # (Pp, 3, S)
+    xt_t = jnp.swapaxes(xt, 1, 2)        # (Pp, 3, Tp)
+    G = PAIR_GROUP
 
     out = pl.pallas_call(
         functools.partial(_p2p_kernel, t_total=T, block_t=block_t),
-        grid=(P, Tp // block_t),
+        grid=(Pp // G, Tp // block_t),
         in_specs=[
-            pl.BlockSpec((1, S), lambda p, t: (p, 0)),
-            pl.BlockSpec((1, 3, S), lambda p, t: (p, 0, 0)),
-            pl.BlockSpec((1, 3, block_t), lambda p, t: (p, 0, t)),
+            pl.BlockSpec((G, S), lambda p, t: (p, 0)),
+            pl.BlockSpec((G, 3, S), lambda p, t: (p, 0, 0)),
+            pl.BlockSpec((G, 3, block_t), lambda p, t: (p, 0, t)),
         ],
-        out_specs=pl.BlockSpec((1, block_t), lambda p, t: (p, t)),
-        out_shape=jax.ShapeDtypeStruct((P, Tp), q.dtype),
+        out_specs=pl.BlockSpec((G, block_t), lambda p, t: (p, t)),
+        out_shape=jax.ShapeDtypeStruct((Pp, Tp), q.dtype),
         interpret=interpret,
-    )(q, xs_t, xt_t)
-    return out[:, :T]
+    )(jnp.pad(q, ((0, pad_p), (0, 0))), xs_t, xt_t)
+    return out[:P, :T]
 
 
 def _heuristic_block_t(S: int, T: int) -> int:
@@ -354,20 +365,32 @@ def best_block_t(S: int, n_pairs: int, T: int = TB, *,
     return choice
 
 
+def stream_target_cover(wt_max: int) -> int:
+    """Lanes a streaming tile must span to hold a whole target row: stream
+    windows start at the 128-aligned flat id below the row's first target
+    (repro.kernels.p2p_stream), so a row of `wt_max` targets may reach 127
+    lanes further."""
+    return wt_max + 127
+
+
 def _heuristic_stream_params(smax: int, wt_max: int) -> tuple[int, int]:
     """Interpret-mode / cold-cache choice for the streaming kernel's 2-D
-    space.  block_t: smallest candidate covering the widest target class
-    (fewer tiles), shrunk until NB=2 buffers of (sources slab + targets +
-    phi) fit a ~1 MB VMEM scratch budget.  n_buffers: 2 — triple buffering
-    only pays when DMA latency exceeds one tile's compute, which the
-    measured sweep (real backends) detects and heuristics can't."""
+    space.  block_t: smallest candidate covering the widest target row in
+    one window (fewer tiles), shrunk until NB=2 buffers of (source slab +
+    target slab) plus the (block_t, source slab) tile temporaries fit a
+    ~4 MB VMEM budget.  n_buffers: 2 — triple buffering only pays when DMA
+    latency exceeds one tile's compute, which the measured sweep (real
+    backends) detects and heuristics can't."""
+    from repro.kernels.p2p_stream import src_width
     nb = 2
+    ws = src_width(smax)
+    cover = stream_target_cover(wt_max)
     choice = BLOCK_CANDIDATES[0]
     for c in BLOCK_CANDIDATES:
-        if nb * (4 * smax + 4 * c) * 4 > 1 << 20:   # (3+1)*SM + (3+1)*bt f32s
+        if (nb * 4 * (ws + c) + 6 * c * ws) * 4 > 4 << 20:
             break
         choice = c
-        if c >= wt_max:
+        if c >= cover:
             break
     return choice, nb
 
@@ -401,7 +424,7 @@ def best_stream_params(smax: int, n_rows: int, wt_max: int, *,
     else:
         mode = "measured"
         import statistics
-        bt_cands = sorted({effective_block_t(wt_max, c)
+        bt_cands = sorted({effective_block_t(stream_target_cover(wt_max), c)
                            for c in BLOCK_CANDIDATES})
         best = float("inf")
         choice = (bt_cands[0], STREAM_BUFFER_CANDIDATES[0])

@@ -28,16 +28,14 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, s1_ref):
     state0 = s0_ref[0].astype(jnp.float32)         # (Dk, Dv)
 
     def step(t, state):
-        # leading dim via dslice, not a bare int: older pallas can't mix int
-        # and Slice indices in one pl.load/pl.store tuple
-        ix = (pl.dslice(0, 1), pl.dslice(t, 1), slice(None))
-        r = pl.load(r_ref, ix)[0, 0].astype(jnp.float32)
-        k = pl.load(k_ref, ix)[0, 0].astype(jnp.float32)
-        v = pl.load(v_ref, ix)[0, 0].astype(jnp.float32)
-        w = pl.load(w_ref, ix)[0, 0].astype(jnp.float32)
+        ix = (0, pl.ds(t, 1), slice(None))
+        r = r_ref[ix][0].astype(jnp.float32)
+        k = k_ref[ix][0].astype(jnp.float32)
+        v = v_ref[ix][0].astype(jnp.float32)
+        w = w_ref[ix][0].astype(jnp.float32)
         kv = k[:, None] * v[None, :]               # (Dk, Dv) outer product
         y = jnp.sum(r[:, None] * (state + u[:, None] * kv), axis=0)  # (Dv,)
-        pl.store(y_ref, ix, y[None, None, :].astype(y_ref.dtype))
+        y_ref[ix] = y[None, :].astype(y_ref.dtype)
         return w[:, None] * state + kv
 
     state = jax.lax.fori_loop(0, C, step, state0)

@@ -65,13 +65,10 @@ def host_device_mesh(n: int, axis: str = "ranks"):
 
 
 def make_mesh_compat(shape, axes):
-    """jax.make_mesh with explicit Auto axis_types on jax >= 0.5, plain mesh
-    on older jax (where Auto is the only behavior).  The single home for this
-    version shim — tests and production meshes all route through it."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(shape, axes,
-                             axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """jax.make_mesh with explicit Auto axis_types — the single home for
+    mesh construction; tests and production meshes all route through it."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -174,18 +174,9 @@ def _moe_shard_map(x, p, cfg, par):
                 P(model, fsdp, None), P(model, fsdp, None),
                 P(model, None, fsdp))
     out_specs = (P(dp, None, None), P())
-    if hasattr(jax, "shard_map"):            # jax >= 0.6 top-level API
-        fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, axis_names=manual,
-                           check_vma=False)
-    else:                                    # older jax: experimental API
-        from jax.experimental.shard_map import shard_map as _shard_map
-        auto = frozenset(mesh.axis_names) - set(manual)
-        kw = dict(check_rep=False)
-        if auto:
-            kw["auto"] = auto
-        fn = _shard_map(body, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs, **kw)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, axis_names=manual,
+                       check_vma=False)
     y, aux = fn(x, p["router"].astype(jnp.float32), p["w_gate"], p["w_up"],
                 p["w_down"])
     return y, aux

@@ -15,7 +15,7 @@ _SCRIPT = textwrap.dedent("""
     import jax.numpy as jnp
     import numpy as np
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from repro.core import collectives as C
 
     from repro.launch.mesh import make_mesh_compat as make_mesh

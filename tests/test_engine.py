@@ -339,3 +339,48 @@ def test_use_pallas_flag_warns_once_and_is_honored():
 
 # The hypothesis property sweep lives in test_engine_property.py (module-
 # level importorskip would skip this whole file where hypothesis is absent).
+
+
+def test_m2l_accumulate_chunked_matches_one_shot(monkeypatch):
+    """Deployment-size far fields run M2L in fixed chunks of pairs; a
+    chunked accumulation (ragged last chunk included) must give the
+    one-shot sum up to f32 scatter order."""
+    import jax.numpy as jnp
+    from repro.core.engine import m2l as m2l_mod
+    from repro.core.engine.m2l import m2l_accumulate
+    ops = get_operators(4)
+    rng = np.random.default_rng(3)
+    C, n = 40, 1000
+    M = jnp.asarray(rng.normal(size=(C, ops.nk)), jnp.float32)
+    src = jnp.asarray(rng.integers(0, C, n))
+    tgt = jnp.asarray(rng.integers(0, C, n))
+    d = jnp.asarray(rng.normal(size=(n, 3)) + 4.0, jnp.float32)
+    mask = jnp.asarray(rng.integers(0, 2, n), jnp.float32)
+    L0 = jnp.zeros_like(M)
+    whole = np.asarray(m2l_accumulate(ops, M, L0, src, tgt, d, mask))
+    monkeypatch.setattr(m2l_mod, "M2L_CHUNK", 128)
+    chunked = np.asarray(m2l_accumulate(ops, M, L0, src, tgt, d, mask))
+    np.testing.assert_allclose(chunked, whole, rtol=1e-5,
+                               atol=1e-6 * np.abs(whole).max())
+
+
+def test_p2p_rows_vals_chunked_matches_one_shot(monkeypatch):
+    """The sharded engine's jnp near field runs gathered rows in chunks;
+    rows are independent, so chunking (ragged last chunk included) must
+    not change a value beyond f32 rounding."""
+    import jax.numpy as jnp
+    from repro.core.engine import p2p as p2p_mod
+    from repro.core.engine.p2p import p2p_rows_vals
+    rng = np.random.default_rng(4)
+    N, B, wt, ws = 300, 77, 16, 8
+    x = jnp.asarray(rng.uniform(size=(N, 3)), jnp.float32)
+    q = jnp.asarray(rng.uniform(-1, 1, N), jnp.float32)
+    t_idx = jnp.asarray(rng.integers(0, N, (B, wt)))
+    s_idx = jnp.asarray(rng.integers(0, N, (B, ws)))
+    s_valid = jnp.asarray(rng.integers(0, 2, (B, ws)).astype(bool))
+    mask = jnp.asarray(rng.integers(0, 2, B), jnp.float32)
+    whole = np.asarray(p2p_rows_vals(x, x, q, t_idx, s_idx, s_valid, mask))
+    monkeypatch.setattr(p2p_mod, "P2P_ROW_CHUNK", 16)
+    chunked = np.asarray(p2p_rows_vals(x, x, q, t_idx, s_idx, s_valid, mask))
+    assert chunked.shape == (B, wt)
+    np.testing.assert_allclose(chunked, whole, rtol=1e-6, atol=1e-6)

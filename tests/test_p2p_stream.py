@@ -17,10 +17,11 @@ from repro.core.api import (FMMSession, PartitionSpec, execute_geometry,
 from repro.core.distributions import make_distribution
 from repro.core.engine import (DeviceEngine, ExecutableCache,
                                build_p2p_stream_tables, default_p2p_stream)
+from repro.core.engine.schedules import stream_out_index
 from repro.core.engine.p2p import (p2p_stream_gathered, p2p_stream_vals,
                                    stream_payload)
 from repro.kernels.p2p import p2p_pallas
-from repro.kernels.p2p_stream import p2p_stream
+from repro.kernels.p2p_stream import p2p_stream, src_width
 
 RTOL, ATOL = 1e-6, 2e-5          # x64 engine tolerances
 F32_RTOL, F32_ATOL = 1e-4, 1e-4
@@ -55,24 +56,29 @@ def test_stream_kernel_bitwise_vs_gathered_pallas():
     provides ragged width classes, partial target tails (tgt_len < block_t)
     and dead padding tiles."""
     _, _, stream, payload = _stream_fixture()
-    meta = stream["meta"]
+    meta, trange = stream["meta"].reshape(-1, 4), stream["tgt_range"]
     bt, smax = stream["block_t"], stream["smax"]
-    live = meta[:, 3] > 0
+    ws = src_width(smax)
+    live = meta[:, 2] > meta[:, 1]
     assert live.any() and (~live).any()          # dead padding tiles exist
-    assert (meta[live, 3] < bt).any()            # partial tails exist
-    assert len({int(r) for r in meta[live, 1]}) > 1   # ragged source widths
+    assert (trange[1, live] - trange[0, live] < bt).any()   # partial tails
+    assert len({int(r) for r in meta[live, 2] - meta[live, 1]}) > 1
+    assert (meta[live, 1] > 0).any()             # unaligned source runs
+    assert (trange[0, live] > meta[live, 3]).any()   # unaligned target runs
+    assert np.all(meta[:, [0, 3]] % 128 == 0)    # every DMA tile-aligned
 
-    out = np.asarray(p2p_stream(jnp.asarray(meta), jnp.asarray(payload),
+    out = np.asarray(p2p_stream(jnp.asarray(stream["meta"]),
+                                jnp.asarray(payload),
                                 block_t=bt, smax=smax, n_buffers=2,
                                 interpret=True))
 
     # gathered reference: identical slab values through p2p_pallas
     m = meta[live]
-    lanes = np.arange(smax)
-    qs = np.where(lanes[None, :] < m[:, 1:2],
-                  payload[3][m[:, 0:1] + lanes[None, :]], 0.0)
+    lanes = np.arange(ws)
+    in_run = (lanes[None, :] >= m[:, 1:2]) & (lanes[None, :] < m[:, 2:3])
+    qs = np.where(in_run, payload[3][m[:, 0:1] + lanes[None, :]], 0.0)
     xs = payload[:3, m[:, 0:1] + lanes[None, :]].transpose(1, 2, 0)
-    xt = payload[:3, m[:, 2:3] + np.arange(bt)[None, :]].transpose(1, 2, 0)
+    xt = payload[:3, m[:, 3:4] + np.arange(bt)[None, :]].transpose(1, 2, 0)
     ref = np.asarray(p2p_pallas(jnp.asarray(qs, jnp.float32),
                                 jnp.asarray(xs), jnp.asarray(xt),
                                 interpret=True, block_t=bt))
@@ -80,7 +86,8 @@ def test_stream_kernel_bitwise_vs_gathered_pallas():
     assert np.all(out[~live] == 0.0)             # dead tiles: exact zeros
 
     # pipeline depth must not change a single bit either
-    out3 = np.asarray(p2p_stream(jnp.asarray(meta), jnp.asarray(payload),
+    out3 = np.asarray(p2p_stream(jnp.asarray(stream["meta"]),
+                                 jnp.asarray(payload),
                                  block_t=bt, smax=smax, n_buffers=3,
                                  interpret=True))
     assert np.array_equal(out3.view(np.uint32), out.view(np.uint32))
@@ -108,12 +115,11 @@ def test_stream_tables_cover_exactly_the_bucket_work():
     gathered buckets cover, with identical multiplicity — the accumulation
     is a scatter-add, so coverage equality IS value equality."""
     _, eng, stream, _ = _stream_fixture()
+    out_idx, out_valid = stream_out_index(
+        stream["meta"], stream["tgt_range"], stream["block_t"])
     got = {}
-    for i in range(stream["n_tiles"]):
-        for lane in range(stream["block_t"]):
-            if stream["out_valid"][i, lane]:
-                k = int(stream["out_idx"][i, lane])
-                got[k] = got.get(k, 0) + 1
+    for k in out_idx[out_valid]:
+        got[int(k)] = got.get(int(k), 0) + 1
     want = {}
     for b in eng.tables.p2p_buckets:
         live = b["mask"] != 0.0
@@ -150,7 +156,8 @@ def test_engine_falls_back_to_gathered_buckets(monkeypatch):
     monkeypatch.setattr(eng_mod, "build_p2p_stream_tables",
                         lambda buckets, bt: None)
     eng = DeviceEngine(geo, use_kernels=False, fused=False, p2p_stream=True)
-    phi = eng.evaluate()
+    with pytest.warns(RuntimeWarning, match="gathered buckets"):
+        phi = eng.evaluate()
     assert eng.p2p_stream is False and eng._stream is None
     np.testing.assert_allclose(phi, execute_geometry(geo),
                                rtol=F32_RTOL, atol=F32_ATOL)
@@ -258,7 +265,7 @@ def test_stream_tables_never_donated(fused_stream):
     assert eng.memo.is_resident(view)
     with pytest.raises(TypeError, match="donate"):
         eng._donatable(view)
-    view2 = eng._aa(eng._stream["out_idx"])
+    view2 = eng._aa(eng._stream["tgt_range"])
     with pytest.raises(TypeError, match="donate"):
         eng._donatable(view2)
 
@@ -292,3 +299,35 @@ def test_default_p2p_stream_off_cpu():
         x, q = _problem(n=200)
         geo = plan_geometry(x, q, PartitionSpec(nparts=2, ncrit=48))
         assert DeviceEngine(geo, use_kernels=False).p2p_stream is False
+
+
+def test_stream_kernel_chunked_table_bitwise(monkeypatch):
+    """The tile table reaches SMEM one chunk at a time, and the slab
+    pipeline restarts at each chunk: any chunking must give the same bits
+    as one chunk over the whole table."""
+    from repro.kernels import p2p_stream as stream_mod
+    _, _, stream, payload = _stream_fixture()
+    meta, pay = jnp.asarray(stream["meta"]), jnp.asarray(payload)
+    kw = dict(block_t=stream["block_t"], smax=stream["smax"], interpret=True)
+    monkeypatch.setattr(stream_mod, "STREAM_CHUNK", stream["n_tiles"])
+    whole = np.asarray(p2p_stream(meta, pay, **kw))
+    for chunk, nb in ((64, 2), (256, 3)):
+        assert stream["n_tiles"] > chunk
+        monkeypatch.setattr(stream_mod, "STREAM_CHUNK", chunk)
+        part = np.asarray(p2p_stream(meta, pay, n_buffers=nb, **kw))
+        assert np.array_equal(part.view(np.uint32), whole.view(np.uint32))
+
+
+def test_measure_stream_warms_each_candidate_once():
+    """The autotune sweep times every candidate several times; only its
+    first measurement compiles and warms it, and the tables built for the
+    sweep's other tilings are dropped once the choice is made."""
+    x, q = _problem(n=400)
+    geo = plan_geometry(x, q, PartitionSpec(nparts=2, ncrit=32))
+    eng = DeviceEngine(geo, use_kernels=True, fused=False, p2p_stream=True)
+    times = [eng._measure_stream(128, nb) for nb in (2, 2, 3)]
+    assert all(np.isfinite(t) and t > 0 for t in times)
+    assert eng._stream_warm == {(128, 2), (128, 3)}
+    assert set(eng._stream_candidates) == {128}
+    eng._stream_tables()
+    assert eng._stream_warm == set() and eng._stream_candidates == {}
