@@ -1,0 +1,8 @@
+"""Share of the window in which no op ran on the device, in percent:
+1 - (union of device op intervals) / (window), from the profiler trace."""
+
+
+def read(run):
+    if run.trace is None:
+        return None
+    return 100.0 * (1.0 - run.trace["busy_s"] / run.trace["window_s"])
