@@ -67,23 +67,24 @@ work you want recorded and read the result with one call::
                                      #   the model still predicts the wire)
     rep["launches"]                  #   entry-computation counts per fused
                                      #   executable (warm evaluate == 1)
-    rep["metrics"]["counters"]       #   memo/cache/donation/autotune counts
+    rep["metrics"]["counters"]       #   memo/cache/upload/autotune counts
 
-To see where the milliseconds went on a timeline, export the chrome
-trace and load it in Perfetto::
+To see where the milliseconds went on a timeline, take a profiler trace
+and load it in Perfetto::
 
-    import json
-    with open("trace.json", "w") as f:
-        json.dump(obs.get_tracer().to_chrome_trace(), f)
+    import jax
+    with jax.profiler.trace("/tmp/fmm-profile", create_perfetto_trace=True):
+        sess.evaluate()
 
-then open https://ui.perfetto.dev (or chrome://tracing) and drop
-`trace.json` onto it — spans appear as nested slices per thread, instant
-events (autotune decisions, exchange probes, cache compiles) as markers.
-`obs.configure(enabled=True, fences=True)` additionally fences span
-boundaries with `block_until_ready`, so per-phase spans measure device
-occupancy instead of async dispatch (leave it off to preserve the fused
-path's single-launch pipelining).  `main()` below prints a per-protocol
-drift line when tracing is on.
+then open https://ui.perfetto.dev and drop the `perfetto_trace.json.gz`
+written under `/tmp/fmm-profile/plugins/profile/<run>/` onto it: every
+span appears on the host thread as `repro.<name>` (`repro.plan.trees`,
+`repro.engine.upload`, ...), and the device ops of the fused launch carry
+their phase (`fmm.upward`, `fmm.m2l`, `fmm.p2p`, ...) in their `op_name`
+metadata; `sess.device_phases()` maps each op to its phase.  Spans never
+wait for the device, so the recorder keeps the fused path's async
+dispatch.  `main()` below prints a per-protocol drift line when tracing is
+on.
 """
 import numpy as np
 

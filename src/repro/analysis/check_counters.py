@@ -23,8 +23,10 @@ rest on (ISSUE 8 regression gate):
      fallback or a typed `ResilienceError` (the accounting identity).
 
 Exits nonzero on any violation, printing each check; writes the full
-`report()` JSON and the chrome trace as artifacts under `--out` so a CI
-failure ships the evidence.
+`report()` JSON and a `jax.profiler` trace of the whole run (`profile/`:
+the `.xplane.pb`, and a Perfetto `perfetto_trace.json.gz` in which the
+`repro.*` spans sit beside the device ops) as artifacts under `--out` so a
+CI failure ships the evidence.
 """
 from __future__ import annotations
 
@@ -39,7 +41,8 @@ def main() -> int:
     os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=4")
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default=None,
-                    help="directory for report JSON + chrome trace artifacts")
+                    help="directory for the report JSON and the profiler "
+                         "trace")
     ap.add_argument("--n", type=int, default=800)
     ap.add_argument("--nparts", type=int, default=8)
     args = ap.parse_args()
@@ -49,6 +52,9 @@ def main() -> int:
 
     from repro import obs
     obs.configure(enabled=True)
+    if args.out:
+        jax.profiler.start_trace(os.path.join(args.out, "profile"),
+                                 create_perfetto_trace=True)
 
     from repro.analysis.hlo_walk import count_entry_launches
     from repro.core.api import FMMSession, PartitionSpec, plan_geometry
@@ -197,15 +203,12 @@ def main() -> int:
 
     # --- artifacts ---------------------------------------------------------
     if args.out:
-        os.makedirs(args.out, exist_ok=True)
+        jax.profiler.stop_trace()
         rep_path = os.path.join(args.out, "session_report.json")
         with open(rep_path, "w") as fh:
             json.dump(rep, fh, indent=1, sort_keys=True, default=str)
-        tracer = obs.get_tracer()
-        trace_path = os.path.join(args.out, "session_trace.json")
-        with open(trace_path, "w") as fh:
-            json.dump(tracer.to_chrome_trace(), fh, default=str)
-        print(f"wrote {rep_path} and {trace_path}")
+        print(f"wrote {rep_path} and the profiler trace under "
+              f"{os.path.join(args.out, 'profile')}")
 
     if failures:
         print(f"\n{len(failures)} invariant violation(s)")

@@ -89,6 +89,45 @@ def count_entry_launches(txt: str) -> int:
     return sum(1 for e in entries if e in comps)
 
 
+_INSTR_RE = re.compile(r"^(?:ROOT\s+)?%?([\w.\-]+)\s*=")
+_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+
+
+def op_phases(txt: str, prefix: str = "fmm.") -> dict:
+    """{instruction name: phase} from the `op_name` metadata of compiled
+    HLO text (`Compiled.as_text()`): the phase is the innermost component
+    of the op_name path that starts with `prefix`, so nested
+    `jax.named_scope`s resolve to the deepest one.  Instructions without
+    such a component are left out.
+
+    These are the names a device trace's `XLA Ops` line carries: a fusion
+    takes its root's op_name (ops of two phases fused into one count for
+    the root's phase), a `while` its loop's, and the ops of a loop body
+    appear too, nested inside their loop's op (`top_level_ops` tells them
+    apart)."""
+    out = {}
+    for line in txt.splitlines():
+        m = _INSTR_RE.match(line.strip())
+        if not m:
+            continue
+        meta = _OP_NAME_RE.search(line)
+        if not meta:
+            continue
+        hits = [c for c in meta.group(1).split("/") if c.startswith(prefix)]
+        if hits:
+            out[m.group(1)] = hits[-1]
+    return out
+
+
+def top_level_ops(txt: str) -> set:
+    """Instruction names of the ENTRY computation: the ops a device trace
+    times one after another.  Loop bodies, branches, calls, fusion
+    internals and reducers are left out, since their time lies inside an
+    op of this set."""
+    lines = parse_computations(txt).get(_entry_name(txt), [])
+    return {m.group(1) for m in map(_INSTR_RE.match, lines) if m}
+
+
 def _trip_count(cond_lines) -> int:
     """Largest integer constant in the while condition ~= trip bound."""
     best = 1

@@ -98,7 +98,6 @@ def observability_section(rep: dict) -> str:
     lines = ["## §Observability — session flight recorder\n"]
     o = rep.get("obs", {})
     lines.append(f"tracing: {'on' if o.get('enabled') else 'off'}"
-                 f" · fences: {'on' if o.get('fences') else 'off'}"
                  f" · events: {o.get('events', 0)}"
                  f" · dropped: {o.get('dropped', 0)}\n")
     timings = rep.get("timings", {})

@@ -288,6 +288,7 @@ class DeviceMemo:
         # host buffer on dtype-preserving uploads, which would keep replaced
         # arrays alive through the cached device view and defeat eviction
         dev = jnp.array(arr, dtype=dtype)
+        obs.counter_add("engine.h2d_bytes", dev.nbytes)
         try:
             anchor = weakref.ref(arr, lambda _, k=key: self._views.pop(k, None))
         except TypeError:                   # non-weakrefable input: pin it
@@ -808,6 +809,23 @@ class FMMSession:
         cache = eng.exe_cache if eng is not None else resolve_cache(self.exe_cache)
         return cache.stats()
 
+    def device_phases(self) -> dict:
+        """{HLO instruction: phase} of the warm fused evaluate's top-level
+        ops, the names a device trace's `XLA Ops` line carries (`fmm.upward`,
+        `fmm.m2l`, ... — `engine.fused.build_fused_evaluate`); summing a
+        trace's op times by this map gives each phase's device time, since
+        a loop's op already covers its body's.  `{}` while no fused
+        evaluate executable has been built."""
+        eng = self._engine
+        hit = (eng._entries.get(("evaluate", bool(jax.config.jax_enable_x64)))
+               if eng is not None else None)
+        if hit is None:
+            return {}
+        from repro.analysis.hlo_walk import op_phases, top_level_ops
+        text = hit[0].hlo_text
+        top = top_level_ops(text)
+        return {op: ph for op, ph in op_phases(text).items() if op in top}
+
     def report(self, *, measure_exchange: bool | None = None,
                protocols=None, reps: int = 3) -> dict:
         """One structured flight-recorder dict for this session: per-span
@@ -823,7 +841,6 @@ class FMMSession:
         tracer = obs.get_tracer()
         rep: dict = {
             "obs": {"enabled": obs.enabled(),
-                    "fences": obs.fences_enabled(),
                     "events": len(tracer.events) if tracer else 0,
                     "dropped": tracer.dropped if tracer else 0},
             "timings": tracer.summary() if tracer else {},
@@ -1097,23 +1114,25 @@ class FMMSession:
         geo = self._geo
         spec = geo.spec
         P = spec.nparts
-        new_x = np.array(new_x, dtype=np.float64)
-        if new_x.shape != (geo.n, 3):
-            raise ValueError(f"step: expected positions {(geo.n, 3)}, "
-                             f"got {new_x.shape}")
-        if not np.isfinite(new_x).all():
-            raise ValueError("new_x: positions contain non-finite values "
-                             "(NaN/Inf); refusing to poison the cached "
-                             "geometry")
-        q_unchanged = new_q is None
-        new_q = geo.q0 if new_q is None else np.array(new_q, dtype=np.float64)
-        if new_q.shape != (geo.n,):
-            raise ValueError(f"step: expected charges {(geo.n,)}, "
-                             f"got {new_q.shape}")
-        if not np.isfinite(new_q).all():
-            raise ValueError("new_q: charges contain non-finite values "
-                             "(NaN/Inf)")
-        q_unchanged = q_unchanged or np.array_equal(new_q, geo.q0)
+        with obs.span("step.check"):
+            new_x = np.array(new_x, dtype=np.float64)
+            if new_x.shape != (geo.n, 3):
+                raise ValueError(f"step: expected positions {(geo.n, 3)}, "
+                                 f"got {new_x.shape}")
+            if not np.isfinite(new_x).all():
+                raise ValueError("new_x: positions contain non-finite "
+                                 "values (NaN/Inf); refusing to poison the "
+                                 "cached geometry")
+            q_unchanged = new_q is None
+            new_q = (geo.q0 if new_q is None
+                     else np.array(new_q, dtype=np.float64))
+            if new_q.shape != (geo.n,):
+                raise ValueError(f"step: expected charges {(geo.n,)}, "
+                                 f"got {new_q.shape}")
+            if not np.isfinite(new_q).all():
+                raise ValueError("new_q: charges contain non-finite values "
+                                 "(NaN/Inf)")
+            q_unchanged = q_unchanged or np.array_equal(new_q, geo.q0)
 
         # Batched device revalidation: a warm engine scores every partition's
         # drift (and changed flag) in ONE launch from a single new_x upload —
@@ -1125,7 +1144,8 @@ class FMMSession:
         use_dev = eng is not None and q_unchanged
         if use_dev:
             try:
-                delta, stale = eng.step_drift(new_x)
+                with obs.span("step.drift"):
+                    delta, stale = eng.step_drift(new_x)
             except Exception as exc:
                 if not self.resilience.enabled:
                     raise
@@ -1164,16 +1184,19 @@ class FMMSession:
         if not use_dev:
             if eng is not None:
                 eng.discard_pending()
-            delta = np.zeros(P)             # drift vs structure reference
-            stale = np.zeros(P, dtype=bool)  # numeric payload out of date
-            for j in range(P):
-                idx = geo.owners[j]
-                if len(idx) == 0:
-                    continue
-                delta[j] = math.sqrt(float(
-                    ((new_x[idx] - geo.x_ref[idx]) ** 2).sum(axis=1).max()))
-                stale[j] = (not np.array_equal(new_x[idx], geo.x0[idx])
-                            or not np.array_equal(new_q[idx], geo.q0[idx]))
+            with obs.span("step.drift"):
+                delta = np.zeros(P)              # drift vs structure ref.
+                stale = np.zeros(P, dtype=bool)  # numeric payload stale
+                for j in range(P):
+                    idx = geo.owners[j]
+                    if len(idx) == 0:
+                        continue
+                    delta[j] = math.sqrt(float(
+                        ((new_x[idx] - geo.x_ref[idx]) ** 2)
+                        .sum(axis=1).max()))
+                    stale[j] = (
+                        not np.array_equal(new_x[idx], geo.x0[idx])
+                        or not np.array_equal(new_q[idx], geo.q0[idx]))
 
         rebuilt = tuple(int(j) for j in range(P)
                         if stale[j] and delta[j] > geo.slack[j])
@@ -1195,21 +1218,25 @@ class FMMSession:
         # the engine recomputes every drifting partition's multipoles in one
         # batched launch from the restacked (x, q) payload.
         defer = self.engine_enabled and not rebuilt
-        self._geo = self._advance(geo, new_x, new_q, delta,
-                                  set(rebuilt), set(refreshed),
-                                  defer_numeric=defer)
+        with obs.span("step.advance"):
+            self._geo = self._advance(geo, new_x, new_q, delta,
+                                      set(rebuilt), set(refreshed),
+                                      defer_numeric=defer)
         self._phi = None
         if rebuilt:                         # bytes matrix / adjacency changed
             self._comm_cache.clear()
             self._engine = None             # structure changed: tables stale
             self._dist = None               # wire layout / spans changed too
         else:
-            if self._engine is not None:
-                self._engine.refresh_payload(self._geo, use_pending=use_dev)
-            if self._dist is not None:
-                # dist recomputes multipoles AND LET wire payloads on device
-                # from the restacked (x, q) — no host LET refresh needed
-                self._dist.refresh_payload(self._geo)
+            with obs.span("step.restack"):
+                if self._engine is not None:
+                    self._engine.refresh_payload(self._geo,
+                                                 use_pending=use_dev)
+                if self._dist is not None:
+                    # dist recomputes multipoles AND LET wire payloads on
+                    # device from the restacked (x, q) — no host LET
+                    # refresh needed
+                    self._dist.refresh_payload(self._geo)
         return report
 
     @staticmethod

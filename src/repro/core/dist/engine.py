@@ -423,8 +423,8 @@ class ShardedEngine:
                 self._rank_tabs
             M_flat, pool = _pack(x, q, pt, rt, **statics)
             ex, rtabs = self._exchange(protocol)
-            outs = sp.fence(_far(x, q, M_flat, ex(pool, rtabs), pt, rt,
-                                 n_buckets=len(self.p2p_buckets), **statics))
+            outs = _far(x, q, M_flat, ex(pool, rtabs), pt, rt,
+                        n_buckets=len(self.p2p_buckets), **statics)
             obs.counter_add("dist.evaluations")
             if obs.enabled():
                 sp.set({"protocol": protocol, "n_ranks": self.n_ranks})

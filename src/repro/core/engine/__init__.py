@@ -46,7 +46,7 @@ from repro import obs
 from repro.core.engine import fused as _fused_mod
 from repro.core.engine.exe_cache import (ExecutableCache, GLOBAL_CACHE,
                                          resolve_cache)
-from repro.core.engine.m2l import far_tail_kernel, m2p_vals_kernel
+from repro.core.engine.m2l import far_tail_kernel, m2l_rows, m2p_vals_kernel
 from repro.core.engine.p2p import p2p_bucket_vals, p2p_stream_vals
 from repro.core.engine.schedules import (BatchedUpwardSchedule, EngineTables,
                                          build_batched_upward,
@@ -167,6 +167,11 @@ class DeviceEngine:
         self._x_pad, self._q_pad = stack_bodies(geometry.trees,
                                                 self.tables.n_bodies_max)
         self._ops = get_operators(geometry.p)
+        # M2L rows per far-field launch: (live, run incl. padding), for the
+        # m2l.pairs_live / m2l.pairs_padded counters
+        m2l_mask = self.tables.m2l["mask"]
+        self._m2l_rows = (int(np.count_nonzero(m2l_mask)),
+                          m2l_rows(len(m2l_mask)))
         self._M = None               # cached device multipoles (P, Cmax, nk)
         self._x_ref_pad = None       # stacked slack reference, built lazily
         self._pending_x_pad = None   # device payload staged by step_drift
@@ -216,12 +221,12 @@ class DeviceEngine:
                     "buffers are deleted after the launch, which would "
                     "poison the memo (engine.fused donation contract); pass "
                     "a fresh upload or a previous fused output instead")
-            obs.counter_add("engine.donate.reuse")
             return arr if dtype is None else jnp.asarray(arr, dtype)
         # jnp.array (copy), never asarray: CPU zero-copy uploads alias the
         # caller's host buffer, and XLA would scribble over it on donation
-        obs.counter_add("engine.donate.upload")
-        return jnp.array(np.asarray(arr), dtype=dtype)
+        dev = jnp.array(np.asarray(arr), dtype=dtype)
+        obs.counter_add("engine.h2d_bytes", dev.nbytes)
+        return dev
 
     def _payload_device(self):
         """The (x_pad, q_pad) payload as donatable device buffers: fresh
@@ -380,25 +385,32 @@ class DeviceEngine:
         self._entries[(kind, x64)] = (entry, tabs)
         return entry, tabs
 
+    def _count_far_field(self) -> None:
+        obs.counter_add("m2l.pairs_live", self._m2l_rows[0])
+        obs.counter_add("m2l.pairs_padded", self._m2l_rows[1])
+
+    def _count_stream(self) -> None:
+        obs.counter_add("p2p.stream.launches")
+        obs.counter_add("p2p.stream.tiles", self._stream["n_live_tiles"])
+        obs.counter_add("p2p.stream.grid_tiles", self._stream["n_tiles"])
+
     def _evaluate_fused(self):
         """One donated launch: payload in, potential (and multipoles) out.
         The threaded-through payload outputs rebind the engine's handles —
         XLA aliases them onto the donated inputs' storage."""
-        with obs.span("engine.fused_evaluate") as sp:
+        with obs.span("engine.fused_evaluate"):
             # simulated-OOM seam: a RESOURCE_EXHAUSTED here is what an
             # oversubscribed accelerator raises on the entry launch, and
             # what the resilience ladder downgrades past
             _faults.fire("fused.launch")
             entry, tabs = self._fused_entry("evaluate")
-            xd, qd = self._payload_device()
-            phi, M, x_out, q_out = sp.fence(entry(xd, qd, tabs))
+            with obs.span("engine.upload"):
+                xd, qd = self._payload_device()
+            phi, M, x_out, q_out = entry(xd, qd, tabs)
             obs.counter_add("engine.fused_launches")
+            self._count_far_field()
             if self._stream is not None:
-                obs.counter_add("p2p.stream.launches")
-                obs.counter_add("p2p.stream.tiles",
-                                self._stream["n_live_tiles"])
-                obs.counter_add("p2p.stream.dma_tiles",
-                                2 * self._stream["n_live_tiles"])
+                self._count_stream()
         self._x_pad, self._q_pad = x_out, q_out
         self._M = M
         self.launch_log.append(("evaluate", entry.key))
@@ -449,10 +461,9 @@ class DeviceEngine:
     def upward(self):
         """Device multipoles (P, n_cells_max, nk); cached per payload."""
         if self._M is None:
-            with obs.span("engine.upward") as sp:
-                self._M = sp.fence(
-                    batched_upward(self._ops, self._x_pad, self._q_pad,
-                                   self.tables.up, asarray=self.memo))
+            with obs.span("engine.upward"):
+                self._M = batched_upward(self._ops, self._x_pad, self._q_pad,
+                                         self.tables.up, asarray=self.memo)
         return self._M
 
     def _phase_values(self):
@@ -465,44 +476,40 @@ class DeviceEngine:
         q = aa(self._q_pad, jnp.float32)
         ut = t.up.tables
 
-        with obs.span("engine.far_field") as sp:
-            l2p_vals = sp.fence(far_tail_kernel(
+        with obs.span("engine.far_field"):
+            l2p_vals = far_tail_kernel(
                 self._ops, M, x,
                 {k: aa(v) for k, v in t.m2l.items()},
                 aa(ut["down_ids"]), aa(ut["down_parents"]),
                 aa(ut["down_mask"]), aa(ut["down_d"]), aa(ut["leaves"]),
                 aa(ut["leaf_mask"]), aa(ut["leaf_centers"]),
-                aa(ut["leaf_idx"])))
+                aa(ut["leaf_idx"]))
+            self._count_far_field()
         yield t.l2p_t_idx, ut["leaf_valid"], l2p_vals
 
         stream = self._stream_tables()
         if stream is not None:
-            with obs.span("engine.p2p_stream") as sp:
-                vals = sp.fence(p2p_stream_vals(
+            with obs.span("engine.p2p_stream"):
+                vals = p2p_stream_vals(
                     x, q, stream, use_kernels=self.use_kernels,
                     interpret=self.interpret, asarray=self.memo,
-                    n_buffers=self._stream_params[1]))
-                obs.counter_add("p2p.stream.launches")
-                obs.counter_add("p2p.stream.tiles",
-                                stream["n_live_tiles"])
-                # two slab DMAs (sources + targets) per live tile
-                obs.counter_add("p2p.stream.dma_tiles",
-                                2 * stream["n_live_tiles"])
+                    n_buffers=self._stream_params[1])
+                self._count_stream()
             yield (*self._stream_out(), vals)
         else:
             for bucket in t.p2p_buckets:
-                with obs.span("engine.p2p_bucket") as sp:
-                    vals = sp.fence(p2p_bucket_vals(
+                with obs.span("engine.p2p_bucket"):
+                    vals = p2p_bucket_vals(
                         x, q, bucket, use_kernels=self.use_kernels,
                         interpret=self.interpret, asarray=self.memo,
-                        to_host=False))
+                        to_host=False)
                 yield bucket["t_idx"], bucket["t_valid"], vals
 
         if t.m2p["b"].shape[0]:
-            with obs.span("engine.m2p") as sp:
-                vals = sp.fence(m2p_vals_kernel(
+            with obs.span("engine.m2p"):
+                vals = m2p_vals_kernel(
                     self._ops, M, x, aa(t.m2p["b"]), aa(t.m2p["centers"]),
-                    aa(t.m2p["mask"]), aa(t.m2p["t_idx"])))
+                    aa(t.m2p["mask"]), aa(t.m2p["t_idx"]))
             yield t.m2p["t_idx"], t.m2p["t_valid"], vals
 
     def evaluate_device(self) -> jnp.ndarray:
@@ -540,10 +547,11 @@ class DeviceEngine:
         Fused mode is one donated launch either way; without x64 the fused
         program can only accumulate in device f32 — marginally looser than
         this host-f64 path (tight-tolerance equivalence holds under x64)."""
-        if jax.config.jax_enable_x64:
-            return np.asarray(self.evaluate_device())
-        if self.fused:
-            return np.asarray(self._evaluate_fused(), np.float64)
+        if jax.config.jax_enable_x64 or self.fused:
+            phi = (self.evaluate_device() if jax.config.jax_enable_x64
+                   else self._evaluate_fused())
+            with obs.span("engine.fetch"):
+                return np.asarray(phi, np.float64)
         t = self.tables
         phi_flat = np.zeros(t.n_parts * t.n_bodies_max)
         for idx, valid, vals in self._phase_values():

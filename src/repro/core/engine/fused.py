@@ -48,6 +48,7 @@ an already-seen shape class pays zero XLA time.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.engine.m2l import far_tail_kernel, m2p_vals_kernel
@@ -133,7 +134,14 @@ def build_fused_evaluate(ops, tables, *, use_kernels: bool,
     tile table (kernels.p2p_stream with `use_kernels`, the XLA slab-gather
     program without) instead of one gather + `pallas_call` per width-class
     bucket — the donated payload is transposed once into the (4, F) slab
-    source in-trace and no per-bucket gathered operands ever hit HBM."""
+    source in-trace and no per-bucket gathered operands ever hit HBM.
+
+    Each phase is a `jax.named_scope`, so the compiled program's op
+    metadata names it (`analysis.hlo_walk.op_phases`): `fmm.upward`,
+    `fmm.m2l` and `fmm.downward` (inside `far_tail_kernel`, the L2P add
+    included), `fmm.p2p` (payload, kernel, lane slots), `fmm.p2p_scatter`
+    (the near-field values' add into the potentials), `fmm.m2p` and
+    `fmm.reorder` (back to original body order)."""
     from repro import obs
     if obs.enabled():
         obs.event("engine.fused_build",
@@ -152,18 +160,20 @@ def build_fused_evaluate(ops, tables, *, use_kernels: bool,
         interp = kops.INTERPRET if interpret is None else bool(interpret)
 
     def fused(x_pad, q_pad, tab):
-        M = batched_upward_kernel(
-            ops, x_pad, q_pad, tab["leaves"], tab["leaf_mask"],
-            tab["leaf_centers"], tab["leaf_idx"], tab["leaf_valid"],
-            tab["up_ids"], tab["up_parents"], tab["up_mask"], tab["up_d"],
-            n_cells=Cmax)
+        with jax.named_scope("fmm.upward"):
+            M = batched_upward_kernel(
+                ops, x_pad, q_pad, tab["leaves"], tab["leaf_mask"],
+                tab["leaf_centers"], tab["leaf_idx"], tab["leaf_valid"],
+                tab["up_ids"], tab["up_parents"], tab["up_mask"],
+                tab["up_d"], n_cells=Cmax)
         m2l = {k: tab[f"m2l_{k}"] for k in ("src", "tgt", "mask", "d")}
-        l2p_vals = far_tail_kernel(
-            ops, M, x_pad, m2l, tab["down_ids"], tab["down_parents"],
-            tab["down_mask"], tab["down_d"], tab["leaves"], tab["leaf_mask"],
-            tab["leaf_centers"], tab["leaf_idx"])
-
-        phi_flat = jnp.zeros(P * Nmax, acc_dtype)
+        # far_tail_kernel scopes its M2L loop as fmm.m2l inside this; the
+        # index constants its vmapped sweep makes carry only this scope
+        with jax.named_scope("fmm.downward"):
+            l2p_vals = far_tail_kernel(
+                ops, M, x_pad, m2l, tab["down_ids"], tab["down_parents"],
+                tab["down_mask"], tab["down_d"], tab["leaves"],
+                tab["leaf_mask"], tab["leaf_centers"], tab["leaf_idx"])
 
         def add(pf, idx, valid, vals):
             contrib = jnp.where(valid.ravel(),
@@ -171,51 +181,61 @@ def build_fused_evaluate(ops, tables, *, use_kernels: bool,
                                 jnp.zeros((), acc_dtype))
             return pf.at[idx.ravel()].add(contrib)
 
-        phi_flat = add(phi_flat, tab["l2p_t_idx"], tab["leaf_valid"],
-                       l2p_vals)
+        with jax.named_scope("fmm.downward"):
+            phi_flat = add(jnp.zeros(P * Nmax, acc_dtype), tab["l2p_t_idx"],
+                           tab["leaf_valid"], l2p_vals)
 
         if stream is not None:
             from repro.core.engine.p2p import (p2p_stream_gathered,
                                                stream_payload)
-            payload = stream_payload(x_pad, q_pad, stream["pad"])
-            if use_kernels:
-                from repro.kernels.p2p_stream import p2p_stream
-                vals = p2p_stream(tab["p2ps_meta"], payload,
-                                  block_t=stream["block_t"],
-                                  smax=stream["smax"], n_buffers=n_buffers,
-                                  interpret=interp)
-            else:
-                vals = p2p_stream_gathered(tab["p2ps_meta"], payload,
-                                           block_t=stream["block_t"],
-                                           smax=stream["smax"])
-            out_idx, out_valid = stream_out_index(
-                tab["p2ps_meta"], tab["p2ps_tgt_range"], stream["block_t"],
-                xp=jnp)
-            phi_flat = add(phi_flat, out_idx, out_valid, vals)
-        else:
-            x_flat = x_pad.reshape(-1, 3)
-            q_flat = q_pad.reshape(-1)
-            for i in range(n_buckets):
-                t_idx, s_idx = tab[f"p2p{i}_t_idx"], tab[f"p2p{i}_s_idx"]
-                xt, xs = x_flat[t_idx], x_flat[s_idx]
-                qs = jnp.where(tab[f"p2p{i}_s_valid"], q_flat[s_idx], 0.0)
+            with jax.named_scope("fmm.p2p"):
+                payload = stream_payload(x_pad, q_pad, stream["pad"])
                 if use_kernels:
-                    vals = p2p_pallas(qs, xs, xt, interpret=interp,
-                                      block_t=block_ts[i]) \
-                        * tab[f"p2p{i}_mask"][:, None]
+                    from repro.kernels.p2p_stream import p2p_stream
+                    vals = p2p_stream(tab["p2ps_meta"], payload,
+                                      block_t=stream["block_t"],
+                                      smax=stream["smax"],
+                                      n_buffers=n_buffers, interpret=interp)
                 else:
-                    vals = _p2p_vals(xt, xs, qs, tab[f"p2p{i}_mask"])
-                phi_flat = add(phi_flat, t_idx, tab[f"p2p{i}_t_valid"], vals)
+                    vals = p2p_stream_gathered(tab["p2ps_meta"], payload,
+                                               block_t=stream["block_t"],
+                                               smax=stream["smax"])
+                out_idx, out_valid = stream_out_index(
+                    tab["p2ps_meta"], tab["p2ps_tgt_range"],
+                    stream["block_t"], xp=jnp)
+            with jax.named_scope("fmm.p2p_scatter"):
+                phi_flat = add(phi_flat, out_idx, out_valid, vals)
+        else:
+            with jax.named_scope("fmm.p2p"):   # adds: fmm.p2p_scatter
+                x_flat = x_pad.reshape(-1, 3)
+                q_flat = q_pad.reshape(-1)
+                for i in range(n_buckets):
+                    t_idx = tab[f"p2p{i}_t_idx"]
+                    s_idx = tab[f"p2p{i}_s_idx"]
+                    xt, xs = x_flat[t_idx], x_flat[s_idx]
+                    qs = jnp.where(tab[f"p2p{i}_s_valid"], q_flat[s_idx],
+                                   0.0)
+                    if use_kernels:
+                        vals = p2p_pallas(qs, xs, xt, interpret=interp,
+                                          block_t=block_ts[i]) \
+                            * tab[f"p2p{i}_mask"][:, None]
+                    else:
+                        vals = _p2p_vals(xt, xs, qs, tab[f"p2p{i}_mask"])
+                    with jax.named_scope("fmm.p2p_scatter"):
+                        phi_flat = add(phi_flat, t_idx,
+                                       tab[f"p2p{i}_t_valid"], vals)
 
         if has_m2p:
-            vals = m2p_vals_kernel(ops, M, x_pad, tab["m2p_b"],
-                                   tab["m2p_centers"], tab["m2p_mask"],
-                                   tab["m2p_t_idx"])
-            phi_flat = add(phi_flat, tab["m2p_t_idx"], tab["m2p_t_valid"],
-                           vals)
+            with jax.named_scope("fmm.m2p"):
+                vals = m2p_vals_kernel(ops, M, x_pad, tab["m2p_b"],
+                                       tab["m2p_centers"], tab["m2p_mask"],
+                                       tab["m2p_t_idx"])
+                phi_flat = add(phi_flat, tab["m2p_t_idx"],
+                               tab["m2p_t_valid"], vals)
 
-        phi = (jnp.zeros(n, acc_dtype)
-               .at[tab["orig_idx"]].set(phi_flat[tab["flat_idx"]]))
+        with jax.named_scope("fmm.reorder"):
+            phi = (jnp.zeros(n, acc_dtype)
+                   .at[tab["orig_idx"]].set(phi_flat[tab["flat_idx"]]))
         return phi, M, x_pad, q_pad
 
     return fused

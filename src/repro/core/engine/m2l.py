@@ -30,13 +30,19 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["far_tail_kernel", "m2p_vals_kernel", "m2l_accumulate",
-           "M2L_CHUNK"]
+           "m2l_rows", "M2L_CHUNK"]
 
 # M2L pairs per step of the accumulation loop.  One pair's operator needs
 # every derivative of 1/r up to order 2(p-1) plus an (nk, nk) translation
 # matrix; materialized for a deployment's millions of pairs at once that is
 # tens of GB, so the pairs run in fixed-size chunks.
 M2L_CHUNK = 1 << 16
+
+
+def m2l_rows(n: int) -> int:
+    """Pair rows `m2l_accumulate` runs for an `n`-row table: `n` in one
+    shot, else rounded up to whole chunks (the padding is masked rows)."""
+    return n if n <= M2L_CHUNK else n + (-n) % M2L_CHUNK
 
 
 def m2l_accumulate(ops, M_src, L_flat, src, tgt, d, mask):
@@ -48,7 +54,7 @@ def m2l_accumulate(ops, M_src, L_flat, src, tgt, d, mask):
     if n <= chunk:
         contrib = ops.m2l_v(M_src[src], d) * mask[:, None]
         return L_flat.at[tgt].add(contrib)
-    pad = (-n) % chunk
+    pad = m2l_rows(n) - n
     if pad:                  # masked rows; d = e_x keeps the operator finite
         src = jnp.pad(src, (0, pad))
         tgt = jnp.pad(tgt, (0, pad))
@@ -63,33 +69,38 @@ def m2l_accumulate(ops, M_src, L_flat, src, tgt, d, mask):
         contrib = ops.m2l_v(M_src[sl(src)], sl(d)) * sl(mask)[:, None]
         return L.at[sl(tgt)].add(contrib)
 
-    return jax.lax.fori_loop(0, (n + pad) // chunk, body, L_flat)
+    return jax.lax.fori_loop(0, m2l_rows(n) // chunk, body, L_flat)
 
 
 @partial(jax.jit, static_argnums=(0,))
 def far_tail_kernel(ops, M, x, m2l, down_ids, down_parents, down_mask,
                     down_d, leaves, leaf_mask, leaf_centers, leaf_idx):
-    """M (P,C,nk), x (P,N,3) + tables -> padded L2P values (P, Bl, W)."""
+    """M (P,C,nk), x (P,N,3) + tables -> padded L2P values (P, Bl, W).
+    The M2L loop and the downward sweep are the device phases `fmm.m2l`
+    and `fmm.downward` (`jax.named_scope`)."""
     P, C, nk = M.shape
-    M_flat = M.reshape(P * C, nk)
-    L_flat = jnp.zeros_like(M_flat)
-    if m2l["src"].shape[0]:
-        L_flat = m2l_accumulate(ops, M_flat, L_flat, m2l["src"], m2l["tgt"],
-                                m2l["d"], m2l["mask"])
-    L = L_flat.reshape(P, C, nk)
+    with jax.named_scope("fmm.m2l"):
+        M_flat = M.reshape(P * C, nk)
+        L_flat = jnp.zeros_like(M_flat)
+        if m2l["src"].shape[0]:
+            L_flat = m2l_accumulate(ops, M_flat, L_flat, m2l["src"],
+                                    m2l["tgt"], m2l["d"], m2l["mask"])
+    with jax.named_scope("fmm.downward"):
+        L = L_flat.reshape(P, C, nk)
 
-    def l2l_one(Lp, ids, parents, mask, d):
-        contrib = ops.l2l_v(Lp[parents], d) * mask[:, None]
-        return Lp.at[ids].add(contrib)
+        def l2l_one(Lp, ids, parents, mask, d):
+            contrib = ops.l2l_v(Lp[parents], d) * mask[:, None]
+            return Lp.at[ids].add(contrib)
 
-    for lvl in range(down_ids.shape[1]):         # slot 0 = level 1 (top)
-        L = jax.vmap(l2l_one)(L, down_ids[:, lvl], down_parents[:, lvl],
-                              down_mask[:, lvl], down_d[:, lvl])
+        for lvl in range(down_ids.shape[1]):     # slot 0 = level 1 (top)
+            L = jax.vmap(l2l_one)(L, down_ids[:, lvl], down_parents[:, lvl],
+                                  down_mask[:, lvl], down_d[:, lvl])
 
-    def l2p_one(Lp, xp, lf, lm, lc, li):
-        return ops.l2p_v(Lp[lf], xp[li], lc) * lm[:, None]
+        def l2p_one(Lp, xp, lf, lm, lc, li):
+            return ops.l2p_v(Lp[lf], xp[li], lc) * lm[:, None]
 
-    return jax.vmap(l2p_one)(L, x, leaves, leaf_mask, leaf_centers, leaf_idx)
+        return jax.vmap(l2p_one)(L, x, leaves, leaf_mask, leaf_centers,
+                                 leaf_idx)
 
 
 @partial(jax.jit, static_argnums=(0,))

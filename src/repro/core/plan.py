@@ -77,13 +77,16 @@ independent axis of the paper plus the hardware floor:
 
 Threaded through all six tiers — not a tier of its own — is the
 observability layer (`repro.obs`): nested wall-time spans around plan
-construction, engine phases and exchanges (with opt-in
-`block_until_ready` fences for device timing), a process-wide metrics
+construction, the step's host stages, engine uploads and exchanges, each
+also a `jax.profiler` annotation so a profile shows it on the device ops'
+clock; named device phases inside the fused launch (`jax.named_scope`,
+read back by `FMMSession.device_phases()`); a process-wide metrics
 registry absorbing the scattered counters (memo hits, cache misses,
-autotune decisions, donation events), and mesh-session probes comparing
-measured exchange time against the LogGP prediction (`model_drift`).
-Disabled — the default — it costs one global load per call site;
-`FMMSession.report()` and `Tracer.to_chrome_trace()` are the read side.
+autotune decisions, host-to-device bytes, live vs padded M2L pairs and
+stream tiles); and mesh-session probes comparing measured exchange time
+against the LogGP prediction (`model_drift`).  Disabled — the default — it
+costs one global load per call site; `FMMSession.report()` and a
+`jax.profiler` trace are the read side.
 
 Also cross-cutting is the resilience tier (`repro.resilience`): named fault
 seams threaded through the stack (`faults.fire(site)` — autotune cache I/O,

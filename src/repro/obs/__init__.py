@@ -2,9 +2,11 @@
 
 One switchboard in front of two instruments:
 
-  - `obs.trace.Tracer` — nested host-wall-time spans (optional
-    `block_until_ready` fences for device timing) + instant events, exported
-    as Perfetto-loadable chrome-trace JSON.
+  - `obs.trace.Tracer` — nested host-wall-time spans + instant events.  Each
+    span is also a `jax.profiler.TraceAnnotation` named `repro.<name>`, so a
+    `jax.profiler` trace shows it on its host line beside the device ops,
+    whose phases the fused program names with `jax.named_scope`
+    (`fmm.upward`, `fmm.m2l`, ...; `analysis.hlo_walk.op_phases`).
   - `obs.metrics.MetricsRegistry` — process-wide counters/gauges/histograms.
 
 Instrumented code never talks to either directly; it calls the module-level
@@ -28,14 +30,14 @@ unconditionally on hot paths — gate them on `obs.enabled()` or pass None.
 
 Enable programmatically::
 
-    obs.configure(enabled=True)            # spans + metrics, no fences
-    obs.configure(enabled=True, fences=True)   # per-phase device timing
+    obs.configure(enabled=True)            # spans + metrics
 
-or via environment (read once at import): ``REPRO_TRACE=1`` enables,
-``REPRO_TRACE_FENCES=1`` additionally fences span boundaries.  Fences are
-opt-in because they serialize the async dispatch stream — the fused
-single-launch serving path should be measured unfenced (dispatch cost)
-unless you explicitly want per-phase device occupancy.
+or via environment (read once at import): ``REPRO_TRACE=1``.  Spans never
+wait for the device, so an enabled recorder keeps the async dispatch of the
+fused launch; take a profile to see device time::
+
+    with jax.profiler.trace(log_dir, create_perfetto_trace=True):
+        sess.evaluate()
 
 `configure(enabled=False)` detaches the tracer but leaves recorded history
 readable via `get_tracer()`; `reset()` clears spans, events and metrics
@@ -51,8 +53,8 @@ from .metrics import GLOBAL_METRICS, MetricsRegistry
 __all__ = [
     "Tracer", "Span", "NullSpan", "NULL_SPAN",
     "MetricsRegistry", "GLOBAL_METRICS",
-    "configure", "enabled", "fences_enabled", "get_tracer", "reset",
-    "span", "event", "fence",
+    "configure", "enabled", "get_tracer", "reset",
+    "span", "event",
     "counter_add", "gauge_set", "observe", "metrics_snapshot",
 ]
 
@@ -62,14 +64,14 @@ _TRACER: Tracer | None = None
 _LAST_TRACER: Tracer | None = None      # history stays readable after disable
 
 
-def configure(enabled: bool = True, *, fences: bool = False,
+def configure(enabled: bool = True, *,
               max_events: int = 100_000) -> Tracer | None:
     """Install (or detach) the process tracer.  Returns the active tracer,
     or None when disabling.  Re-configuring replaces the tracer — prior
     history remains readable through `get_tracer()` until the next enable."""
     global _TRACER, _LAST_TRACER
     if enabled:
-        _TRACER = Tracer(fences=fences, max_events=max_events)
+        _TRACER = Tracer(max_events=max_events)
         _LAST_TRACER = _TRACER
     else:
         _TRACER = None
@@ -78,10 +80,6 @@ def configure(enabled: bool = True, *, fences: bool = False,
 
 def enabled() -> bool:
     return _TRACER is not None
-
-
-def fences_enabled() -> bool:
-    return _TRACER is not None and _TRACER.fences
 
 
 def get_tracer() -> Tracer | None:
@@ -103,8 +101,9 @@ def reset() -> None:
 
 # ------------------------------------------------------------- hot path --
 def span(name: str, attrs=None):
-    """Context manager measuring the enclosed host wall time.  Disabled →
-    the shared NULL_SPAN (no allocation)."""
+    """Context manager measuring the enclosed host wall time, and the
+    profiler annotation `repro.<name>` while it is open.  Disabled → the
+    shared NULL_SPAN (no allocation)."""
     t = _TRACER
     if t is None:
         return NULL_SPAN
@@ -117,17 +116,6 @@ def event(name: str, attrs=None) -> None:
     if t is None:
         return
     t.event(name, attrs)
-
-
-def fence(value):
-    """`block_until_ready(value)` iff fencing is configured; returns value.
-    For call sites that want a fence *between* operations rather than at a
-    span boundary."""
-    t = _TRACER
-    if t is not None and t.fences:
-        import jax
-        jax.block_until_ready(value)
-    return value
 
 
 def counter_add(name: str, value: float = 1.0) -> None:
@@ -152,8 +140,6 @@ def metrics_snapshot() -> dict:
     return GLOBAL_METRICS.snapshot()
 
 
-# Environment opt-in, read once at import: REPRO_TRACE=1 [REPRO_TRACE_FENCES=1]
+# Environment opt-in, read once at import: REPRO_TRACE=1
 if _os.environ.get("REPRO_TRACE", "").strip() in ("1", "true", "on"):
-    configure(enabled=True,
-              fences=_os.environ.get("REPRO_TRACE_FENCES", "").strip()
-              in ("1", "true", "on"))
+    configure(enabled=True)

@@ -1,45 +1,44 @@
-"""Tracer: nested host-wall-time spans with a Perfetto-loadable export.
+"""Tracer: nested host spans on the profiler's clock.
 
 The flight-recorder half of `repro.obs` (the other half is the metrics
 registry, `obs.metrics`).  A `Tracer` records two event kinds:
 
-  - **spans** — `with tracer.span("engine.upward"):` measures host wall time
+  - **spans** — `with tracer.span("engine.upload"):` records host wall time
     between enter and exit.  Spans nest (a per-thread stack tracks the open
-    parent), carry a process-monotonic id, optional `key=value` attributes,
-    and an optional *device fence*: `sp.fence(arrays)` registers JAX values
-    to `block_until_ready` at span exit, so the recorded duration covers the
-    device work the span launched rather than just the dispatch.  Fencing is
-    opt-in per tracer (`fences=True`) AND per span — the fused single-launch
-    paths stay unfenced by default, preserving the one-entry-launch
-    guarantee's async pipelining.
+    parent), carry a process-monotonic id and optional `key=value`
+    attributes.  Each span also enters a `jax.profiler.TraceAnnotation`
+    named `"repro." + name`, so while a `jax.profiler` trace is being taken
+    the span sits on the trace's host line, on the same clock as the device
+    ops it launched.  A span never waits for the device: device time is
+    read from the trace, where `jax.named_scope` names the fused
+    program's phases (`engine.fused`).
   - **instant events** — `tracer.event("p2p.autotune", {...})` records a
     point-in-time marker (autotune decisions, cache events, probes).
 
-Export: `to_chrome_trace()` renders the Chrome Trace Event Format (`"X"`
-duration events + `"i"` instants) that both `chrome://tracing` and Perfetto
-(https://ui.perfetto.dev) load directly; `summary()` aggregates span wall
-time by name for `FMMSession.report()`.
+`summary()` aggregates span wall time by name for `FMMSession.report()`.
 
 Disabled mode lives one layer up: `repro.obs.span()` returns the shared
 `NULL_SPAN` singleton when no tracer is installed — zero allocations, no
-clock reads — which the overhead test pins (`tests/test_obs.py`).  The
-classes here therefore never check an enabled flag themselves.
+clock reads, no annotation — which the overhead test pins
+(`tests/test_obs.py`).  The classes here therefore never check an enabled
+flag themselves.
 """
 from __future__ import annotations
 
 import itertools
-import os
 import threading
 import time
+
+from jax.profiler import TraceAnnotation
 
 __all__ = ["Span", "NullSpan", "NULL_SPAN", "Tracer"]
 
 
 class NullSpan:
     """The do-nothing span served while tracing is disabled.  A process-wide
-    singleton (`NULL_SPAN`): entering, exiting, annotating and fencing all
-    return immediately without allocating, so instrumented hot paths cost a
-    dict lookup and an `is None` check when the recorder is off."""
+    singleton (`NULL_SPAN`): entering, exiting and annotating all return
+    immediately without allocating, so instrumented hot paths cost a dict
+    lookup and an `is None` check when the recorder is off."""
 
     __slots__ = ()
 
@@ -52,20 +51,18 @@ class NullSpan:
     def set(self, attrs=None):
         return self
 
-    def fence(self, value):
-        return value
-
 
 NULL_SPAN = NullSpan()
 
 
 class Span:
-    """One recorded interval.  Times are `time.perf_counter_ns` ticks
-    relative to the owning tracer's epoch; `sid`/`parent` are the tracer's
-    monotonic span ids (parent -1 = top level)."""
+    """One recorded interval.  Times are `time.perf_counter_ns` ticks;
+    `sid`/`parent` are the tracer's monotonic span ids (parent -1 = top
+    level).  While open, the span is also the profiler annotation
+    `"repro." + name`."""
 
     __slots__ = ("tracer", "name", "attrs", "sid", "parent", "tid",
-                 "t0_ns", "t1_ns", "_fenced")
+                 "t0_ns", "t1_ns", "_note")
 
     def __init__(self, tracer, name, attrs, sid, parent, tid):
         self.tracer = tracer
@@ -76,7 +73,7 @@ class Span:
         self.tid = tid
         self.t0_ns = -1
         self.t1_ns = -1
-        self._fenced = None
+        self._note = None
 
     def set(self, attrs=None):
         """Merge `attrs` into the span's attributes (post-hoc annotation:
@@ -88,26 +85,17 @@ class Span:
                 self.attrs.update(attrs)
         return self
 
-    def fence(self, value):
-        """Register `value` (any pytree of JAX arrays) to be
-        `block_until_ready`-fenced at span exit — only when the tracer was
-        built with `fences=True`; otherwise a pass-through no-op.  Returns
-        `value` so call sites can fence inline: `out = sp.fence(fn())`."""
-        if self.tracer.fences:
-            self._fenced = value
-        return value
-
     def __enter__(self):
         self.tracer._push(self)
+        self._note = TraceAnnotation("repro." + self.name)
+        self._note.__enter__()
         self.t0_ns = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        if self._fenced is not None:
-            import jax
-            jax.block_until_ready(self._fenced)
-            self._fenced = None
         self.t1_ns = time.perf_counter_ns()
+        self._note.__exit__(exc_type, exc, tb)
+        self._note = None
         self.tracer._pop(self)
         return False
 
@@ -121,17 +109,12 @@ class Tracer:
 
     Parameters
     ----------
-    fences : honor `Span.fence` registrations with a `block_until_ready` at
-        span exit (per-phase *device* timing).  Off by default so traced
-        sessions keep the exact async dispatch behavior of untraced ones.
     max_events : ring bound on retained finished events; the oldest half is
         dropped when exceeded (a flight recorder must never OOM the flight).
     """
 
-    def __init__(self, *, fences: bool = False, max_events: int = 100_000):
-        self.fences = bool(fences)
+    def __init__(self, *, max_events: int = 100_000):
         self.max_events = int(max_events)
-        self.epoch_ns = time.perf_counter_ns()
         self.events: list = []          # finished Spans + instant dicts
         self.dropped = 0
         self._ids = itertools.count()
@@ -183,12 +166,11 @@ class Tracer:
                 del self.events[:drop]
                 self.dropped += drop
 
-    # ------------------------------------------------------------- export --
+    # --------------------------------------------------------------- read --
     def clear(self) -> None:
         with self._lock:
             self.events.clear()
             self.dropped = 0
-        self.epoch_ns = time.perf_counter_ns()
 
     def spans(self, name: str | None = None) -> list:
         """Finished spans, oldest first, optionally filtered by name."""
@@ -212,31 +194,3 @@ class Tracer:
         for a in agg.values():
             a["mean_s"] = a["total_s"] / a["count"]
         return agg
-
-    def to_chrome_trace(self) -> dict:
-        """Chrome Trace Event Format JSON (dict — `json.dump` it).  Loadable
-        by Perfetto (ui.perfetto.dev) and chrome://tracing: spans become
-        complete ("X") duration events, instants become "i" events; `ts` and
-        `dur` are microseconds since the tracer epoch."""
-        pid = os.getpid()
-        out = []
-        with self._lock:
-            evs = list(self.events)
-        for e in evs:
-            if isinstance(e, Span):
-                rec = {"name": e.name, "cat": "span", "ph": "X",
-                       "ts": (e.t0_ns - self.epoch_ns) / 1e3,
-                       "dur": (e.t1_ns - e.t0_ns) / 1e3,
-                       "pid": pid, "tid": e.tid,
-                       "args": {"sid": e.sid, "parent": e.parent,
-                                **(e.attrs or {})}}
-            else:
-                rec = {"name": e["name"], "cat": "event", "ph": "i",
-                       "s": "t",
-                       "ts": (e["t_ns"] - self.epoch_ns) / 1e3,
-                       "pid": pid, "tid": e["tid"],
-                       "args": {"sid": e["sid"], "parent": e["parent"],
-                                **(e["attrs"] or {})}}
-            out.append(rec)
-        return {"traceEvents": out, "displayTimeUnit": "ms",
-                "otherData": {"dropped_events": self.dropped}}

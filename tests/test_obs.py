@@ -1,7 +1,7 @@
 """Flight recorder (repro.obs): tracer, metrics, report surfaces.
 
 In-process: span nesting/ordering, the disabled-mode zero-allocation pin,
-chrome-trace schema, metrics-registry isolation, the non-raising stats
+spans as profiler annotations, metrics-registry isolation, the non-raising stats
 surfaces and the one-launch guarantee under tracing.  The 4-device exchange
 probe (wire bytes == rank-aggregated `GeometryPlan.bytes_matrix`, finite
 `model_drift` per protocol) runs in a subprocess so this process keeps a
@@ -103,25 +103,32 @@ def test_disabled_mode_is_zero_allocation():
     assert peak < 8192, f"disabled obs hot path allocated {peak} bytes"
 
 
-def test_chrome_trace_schema():
+def test_span_is_a_profiler_annotation(tmp_path):
+    """An enabled span is `repro.<name>` on the host line of a
+    `jax.profiler` trace, the clock the device ops are timed on; its own
+    record stays for `summary()`."""
+    import jax
+    from jax.profiler import ProfileData
     tr = obs.configure(enabled=True)
-    with obs.span("a", {"n": 2}):
-        obs.event("marker", {"why": "test"})
-    ct = tr.to_chrome_trace()
-    json.dumps(ct)                               # serializable
-    assert ct["displayTimeUnit"] == "ms"
-    assert ct["otherData"]["dropped_events"] == 0
-    evs = ct["traceEvents"]
-    assert len(evs) == 2
-    for e in evs:
-        assert e["ph"] in ("X", "i")
-        assert isinstance(e["name"], str)
-        assert e["ts"] >= 0 and "pid" in e and "tid" in e
-        assert "sid" in e["args"] and "parent" in e["args"]
-    xs = [e for e in evs if e["ph"] == "X"]
-    assert xs[0]["dur"] >= 0 and xs[0]["args"]["n"] == 2
-    ins = [e for e in evs if e["ph"] == "i"]
-    assert ins[0]["s"] == "t" and ins[0]["args"]["why"] == "test"
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs.span("outer"):
+            with obs.span("inner"):
+                jax.numpy.ones(8).sum().block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    host = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith("repro."):
+                        host[ev.name] = (ev.start_ns, ev.duration_ns)
+    assert set(host) == {"repro.outer", "repro.inner"}
+    (o0, od), (i0, idur) = host["repro.outer"], host["repro.inner"]
+    assert o0 <= i0 and i0 + idur <= o0 + od
+    assert [s.name for s in tr.spans()] == ["inner", "outer"]
 
 
 def test_tracer_disable_keeps_history_reset_drops_it():
@@ -206,8 +213,8 @@ def test_meshless_report_structure():
 
 
 def test_traced_fused_evaluate_still_one_entry_launch():
-    """Tracing must not break the one-launch guarantee: spans fence nothing
-    by default, and the fused entry still compiles to ONE entry
+    """Tracing must not break the one-launch guarantee: spans never wait
+    for the device, and the fused entry still compiles to ONE entry
     computation."""
     from repro.analysis.hlo_walk import count_entry_launches
     from repro.core.api import FMMSession

@@ -101,3 +101,22 @@ def test_mac_margins_compiles(one_chip, K):
     _assert_kernel(fn.lower(_sds(one_chip, (K, 3)), _sds(one_chip, (K,)),
                             _sds(one_chip, (K, 3)),
                             _sds(one_chip, (K,))).compile())
+
+
+def test_stream_kernel_keeps_its_name_and_phase(one_chip):
+    """The trace's readers find the stream kernel by its op name
+    `_p2p_stream` and its phase by the fused program's `fmm.p2p` scope."""
+    from repro.analysis.hlo_walk import op_phases, top_level_ops
+    n_tiles = bucket_size(SMOKE_LIVE_TILES[128])
+    width = SMOKE_FLAT_BODIES + 128
+
+    def fn(m, p):
+        with jax.named_scope("fmm.p2p"):
+            return p2p_stream(m, p, block_t=128, smax=SMOKE_SMAX,
+                              interpret=False)
+
+    text = jax.jit(fn).lower(_sds(one_chip, (4 * n_tiles,), jnp.int32),
+                             _sds(one_chip, (4, width))).compile().as_text()
+    kernels = [op for op, ph in op_phases(text).items()
+               if op.startswith("_p2p_stream") and ph == "fmm.p2p"]
+    assert len(kernels) == 1 and kernels[0] in top_level_ops(text)
