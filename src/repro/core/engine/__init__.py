@@ -169,9 +169,9 @@ class DeviceEngine:
         self._ops = get_operators(geometry.p)
         # M2L rows per far-field launch: (live, run incl. padding), for the
         # m2l.pairs_live / m2l.pairs_padded counters
-        m2l_mask = self.tables.m2l["mask"]
-        self._m2l_rows = (int(np.count_nonzero(m2l_mask)),
-                          m2l_rows(len(m2l_mask)))
+        m2l = self.tables.m2l
+        self._m2l_rows = (int(np.count_nonzero(m2l["mask"])),
+                          m2l_rows(int(m2l["n_live"]), len(m2l["mask"])))
         self._M = None               # cached device multipoles (P, Cmax, nk)
         self._x_ref_pad = None       # stacked slack reference, built lazily
         self._pending_x_pad = None   # device payload staged by step_drift

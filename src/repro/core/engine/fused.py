@@ -166,7 +166,8 @@ def build_fused_evaluate(ops, tables, *, use_kernels: bool,
                 tab["leaf_centers"], tab["leaf_idx"], tab["leaf_valid"],
                 tab["up_ids"], tab["up_parents"], tab["up_mask"],
                 tab["up_d"], n_cells=Cmax)
-        m2l = {k: tab[f"m2l_{k}"] for k in ("src", "tgt", "mask", "d")}
+        m2l = {k: tab[f"m2l_{k}"]
+               for k in ("src", "tgt", "mask", "d", "n_live")}
         # far_tail_kernel scopes its M2L loop as fmm.m2l inside this; the
         # index constants its vmapped sweep makes carry only this scope
         with jax.named_scope("fmm.downward"):

@@ -9,7 +9,8 @@ its own Python loop.  The engine flattens all of it:
     local block first then senders ascending, matching the reference
     accumulation order — with *global* cell ids (`p * n_cells_max + c`), and
     applied as `ops.m2l_v` + segment-sum scatters into the flat local
-    array, `M2L_CHUNK` pairs at a time (`m2l_accumulate`).  Grafted-LET
+    array, `M2L_CHUNK` pairs at a time over the chunks that hold the
+    table's `n_live` live rows (`m2l_accumulate`).  Grafted-LET
     sources were translated to sender-global ids at table-build time, so
     remote M2L reads the sender's device multipoles directly: no LET
     payload ever crosses the host boundary.
@@ -39,22 +40,30 @@ __all__ = ["far_tail_kernel", "m2p_vals_kernel", "m2l_accumulate",
 M2L_CHUNK = 1 << 16
 
 
-def m2l_rows(n: int) -> int:
-    """Pair rows `m2l_accumulate` runs for an `n`-row table: `n` in one
-    shot, else rounded up to whole chunks (the padding is masked rows)."""
-    return n if n <= M2L_CHUNK else n + (-n) % M2L_CHUNK
+def m2l_rows(n_live, n: int):
+    """Pair rows `m2l_accumulate` runs for an `n`-row table whose first
+    `n_live` rows are live: `n` in one shot, else the live rows rounded up
+    to whole chunks (the rows after them are masked padding, never run).
+    `n_live` may be a traced scalar; the loop and the `m2l.pairs_padded`
+    counter share this rule."""
+    return n if n <= M2L_CHUNK else n_live + (-n_live) % M2L_CHUNK
 
 
-def m2l_accumulate(ops, M_src, L_flat, src, tgt, d, mask):
+def m2l_accumulate(ops, M_src, L_flat, src, tgt, d, mask, n_live=None):
     """L_flat[tgt] += mask * M2L(M_src[src], d) over every pair row, in
     chunks of `M2L_CHUNK` rows so the per-pair operator temporaries stay
-    bounded.  Tables of at most one chunk take the one-shot path."""
+    bounded.  Tables of at most one chunk take the one-shot path.
+
+    `n_live` (a traced 0-d int, so every live count of one table shape
+    shares one program) says that the rows from it on are masked padding:
+    the loop then runs only the chunks that hold live rows.  Without it
+    the loop runs the whole table."""
     chunk = M2L_CHUNK
     n = src.shape[0]
     if n <= chunk:
         contrib = ops.m2l_v(M_src[src], d) * mask[:, None]
         return L_flat.at[tgt].add(contrib)
-    pad = m2l_rows(n) - n
+    pad = m2l_rows(n, n) - n
     if pad:                  # masked rows; d = e_x keeps the operator finite
         src = jnp.pad(src, (0, pad))
         tgt = jnp.pad(tgt, (0, pad))
@@ -63,13 +72,22 @@ def m2l_accumulate(ops, M_src, L_flat, src, tgt, d, mask):
             [d, jnp.broadcast_to(jnp.asarray([1.0, 0.0, 0.0], d.dtype),
                                  (pad, 3))], axis=0)
 
-    def body(i, L):
+    live = n if n_live is None else n_live
+
+    # the bound is computed in the condition: computed before the loop, it
+    # cost the v5e program 32 KiB more temporaries
+    def more(carry):
+        return carry[0] * chunk < m2l_rows(live, n)
+
+    def body(carry):
+        i, L = carry
+
         def sl(a):
             return jax.lax.dynamic_slice_in_dim(a, i * chunk, chunk)
         contrib = ops.m2l_v(M_src[sl(src)], sl(d)) * sl(mask)[:, None]
-        return L.at[sl(tgt)].add(contrib)
+        return i + 1, L.at[sl(tgt)].add(contrib)
 
-    return jax.lax.fori_loop(0, m2l_rows(n) // chunk, body, L_flat)
+    return jax.lax.while_loop(more, body, (jnp.int32(0), L_flat))[1]
 
 
 @partial(jax.jit, static_argnums=(0,))
@@ -84,7 +102,8 @@ def far_tail_kernel(ops, M, x, m2l, down_ids, down_parents, down_mask,
         L_flat = jnp.zeros_like(M_flat)
         if m2l["src"].shape[0]:
             L_flat = m2l_accumulate(ops, M_flat, L_flat, m2l["src"],
-                                    m2l["tgt"], m2l["d"], m2l["mask"])
+                                    m2l["tgt"], m2l["d"], m2l["mask"],
+                                    m2l["n_live"])
     with jax.named_scope("fmm.downward"):
         L = L_flat.reshape(P, C, nk)
 

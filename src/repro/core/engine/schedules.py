@@ -122,7 +122,8 @@ class EngineTables:
     p: int                       # expansion order
     up: BatchedUpwardSchedule
     m2l: dict = field(repr=False)        # src/tgt (B,) i64 global cells ·
-                                         # mask (B,) f32 · d (B,3) f32
+                                         # mask (B,) f32 · d (B,3) f32 ·
+                                         # n_live () i32 rows before padding
     m2p: dict = field(repr=False)        # b (B,) i64 global cells · mask f32
                                          # · centers (B,3) f32 · t_idx (B,wt)
                                          # i64 global bodies · t_valid bool
@@ -400,8 +401,12 @@ def build_engine_tables(geo) -> EngineTables:
         n = len(m2l["src"])
         m2l = _pad_rows(m2l, n, bucket_size(n), replicate=True)
     else:
+        n = 0
         m2l = {"src": np.zeros(0, np.int64), "tgt": np.zeros(0, np.int64),
                "mask": np.zeros(0, np.float32), "d": np.zeros((0, 3), np.float32)}
+    # rows before the padding; the M2L loop runs only the chunks that hold
+    # them (0-d, so every live count of a shape class shares one program)
+    m2l["n_live"] = np.asarray(n, np.int32)
     if m2p_rows["b"]:
         m2p = cat(m2p_rows)
         n = len(m2p["b"])

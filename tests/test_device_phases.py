@@ -122,7 +122,8 @@ def test_counters_of_a_matvec_call(warm):
     c = obs.metrics_snapshot()["counters"]
     mask = eng.tables.m2l["mask"]
     assert c["m2l.pairs_live"] == np.count_nonzero(mask) > 0
-    assert c["m2l.pairs_padded"] == m2l_rows(len(mask)) == len(mask)
+    n_live = int(eng.tables.m2l["n_live"])
+    assert c["m2l.pairs_padded"] == m2l_rows(n_live, len(mask)) == len(mask)
     P, nmax = eng.tables.n_parts, eng.tables.n_bodies_max
     x_pad = np.zeros((P, nmax, 3), np.float32)
     q_pad = np.zeros((P, nmax), np.float32)
@@ -132,7 +133,39 @@ def test_counters_of_a_matvec_call(warm):
         assert c["p2p.stream.grid_tiles"] == eng._stream["n_tiles"]
     else:
         assert "p2p.stream.grid_tiles" not in c
-    assert m2l_rows(M2L_CHUNK + 1) == 2 * M2L_CHUNK
+    assert m2l_rows(M2L_CHUNK + 1, M2L_CHUNK + 1) == 2 * M2L_CHUNK
+
+
+@pytest.mark.parametrize("n_live,n,rows", [
+    (100, M2L_CHUNK, M2L_CHUNK),
+    (M2L_CHUNK + 1, 4 * M2L_CHUNK, 2 * M2L_CHUNK),
+    (4 * M2L_CHUNK, 4 * M2L_CHUNK, 4 * M2L_CHUNK)],
+    ids=["one_chunk_table", "ragged_live_count", "full_envelope"])
+def test_m2l_rows_counts_the_rows_the_loop_runs(n_live, n, rows):
+    """One rule for the loop's trip count and the `m2l.pairs_padded`
+    counter: a table of one chunk runs whole, a longer one only the
+    chunks that hold its live rows."""
+    assert m2l_rows(n_live, n) == rows
+    assert m2l_rows(jnp.int32(n_live), n) == rows      # traced, as the loop
+
+
+def test_counters_of_a_multi_chunk_table(monkeypatch):
+    """With chunks smaller than the toy's M2L table, the counter reads
+    the live chunks the fused loop runs, and the sums stay the same."""
+    from repro.core.engine import m2l as m2l_mod
+    whole = _toy(p2p_stream=False)
+    want = whole.evaluate()
+    monkeypatch.setattr(m2l_mod, "M2L_CHUNK", 4)
+    sess = _toy(p2p_stream=False)
+    m2l = sess.engine.tables.m2l
+    n_live, n = int(m2l["n_live"]), len(m2l["mask"])
+    assert 4 < n_live <= n - 4                   # a chunk or more skipped
+    obs.configure(enabled=True)
+    phi = sess.evaluate()
+    c = obs.metrics_snapshot()["counters"]
+    assert c["m2l.pairs_live"] == n_live
+    assert c["m2l.pairs_padded"] == -(-n_live // 4) * 4
+    np.testing.assert_allclose(phi, want, rtol=1e-5, atol=1e-5)
 
 
 def test_step_and_engine_spans(warm):

@@ -364,6 +364,47 @@ def test_m2l_accumulate_chunked_matches_one_shot(monkeypatch):
                                atol=1e-6 * np.abs(whole).max())
 
 
+@pytest.mark.parametrize("n_live", [50, 256, 300, 512],
+                         ids=["inside_first_chunk", "whole_chunks",
+                              "ragged_last_chunk", "fills_table"])
+def test_m2l_accumulate_runs_live_chunks_only(monkeypatch, n_live):
+    """Given the table's live row count (traced), the loop runs only the
+    chunks that hold live rows: the same sums as the whole-table loop over
+    masked padding, and rows past those chunks never reach L, even with
+    mask 1 and operators ~1e16 — so their steps are skipped, not masked."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core.engine import m2l as m2l_mod
+    from repro.core.engine.m2l import m2l_accumulate, m2l_rows
+    monkeypatch.setattr(m2l_mod, "M2L_CHUNK", 128)
+    ops = get_operators(4)
+    rng = np.random.default_rng(7)
+    C, n = 40, 512                               # four chunks
+    M = jnp.asarray(rng.normal(size=(C, ops.nk)), jnp.float32)
+    L0 = jnp.zeros_like(M)
+    src = rng.integers(0, C, n)
+    tgt = rng.integers(0, C, n)
+    d = (rng.normal(size=(n, 3)) + 4.0).astype(np.float32)
+    mask = np.ones(n, np.float32)
+    # padding as build_engine_tables makes it: row 0 repeated, mask 0
+    src[n_live:], tgt[n_live:], d[n_live:], mask[n_live:] = \
+        src[0], tgt[0], d[0], 0.0
+    whole = np.asarray(m2l_accumulate(ops, M, L0, src, tgt, d, mask))
+    live = jax.jit(lambda s, t, dd, m, k: m2l_accumulate(
+        ops, M, L0, s, t, dd, m, k))
+    np.testing.assert_array_equal(
+        np.asarray(live(src, tgt, d, mask, jnp.int32(n_live))), whole)
+
+    end = m2l_rows(n_live, n)                    # rows the loop runs
+    assert end == -(-n_live // 128) * 128
+    mask[end:], d[end:] = 1.0, d[end:] * 1e-3
+    np.testing.assert_array_equal(
+        np.asarray(live(src, tgt, d, mask, jnp.int32(n_live))), whole)
+    if end < n:                                  # they would, if run
+        poisoned = np.asarray(m2l_accumulate(ops, M, L0, src, tgt, d, mask))
+        assert np.abs(poisoned - whole).max() > 1e6 * np.abs(whole).max()
+
+
 def test_p2p_rows_vals_chunked_matches_one_shot(monkeypatch):
     """The sharded engine's jnp near field runs gathered rows in chunks;
     rows are independent, so chunking (ragged last chunk included) must

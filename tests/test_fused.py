@@ -145,6 +145,29 @@ def test_second_same_shape_class_geometry_zero_compiles(shared):
                                rtol=F32_RTOL, atol=F32_ATOL)
 
 
+def test_m2l_live_count_is_a_value_not_a_shape(shared):
+    """Geometries whose M2L tables differ only in their live row count
+    (one pair fewer here, masked as padding) keep one shape class: the
+    count is a 0-d table, so they share one executable."""
+    import dataclasses
+    from repro.core.engine.schedules import shape_class_digest
+    t = shared["sess"].engine.tables
+    m2l = dict(t.m2l)
+    n_live = int(m2l["n_live"])
+    assert m2l["n_live"].shape == () and m2l["n_live"].dtype == np.int32
+    assert 0 < n_live <= len(m2l["mask"])
+    m2l["mask"] = m2l["mask"].copy()
+    m2l["mask"][n_live - 1] = 0.0
+    m2l["n_live"] = np.asarray(n_live - 1, np.int32)
+    fewer = dataclasses.replace(t, m2l=m2l)
+
+    def digest(tables):
+        return shape_class_digest(
+            {k: jnp.asarray(v) for k, v in
+             fused_mod.flatten_eval_tables(tables).items()})
+    assert digest(fewer) == digest(t)
+
+
 def test_different_shape_class_geometry_compiles(shared):
     """Changing the partition count changes the stacked envelope shapes —
     a genuinely new shape class must MISS (one new compilation)."""

@@ -11,7 +11,9 @@ The shapes are those the host planner gives the smoke's configuration
 four P2P width classes, a stream table of 1,478,057 live tiles at
 block_t = 128 and 1,266,392 at 256 and 512 (source width 64), 8 x 131,072
 payload bodies, and 16,384 padded cells per tree, whose first frontier
-capacity is `traversal_caps(16384)[0]`.
+capacity is `traversal_caps(16384)[0]`.  The M2L loop compiles at the
+benchmark's sphere-1m table: 8,388,608 pair rows (2^23) of which
+4,219,692 are live, over 8 x 16,384 cells of p = 4 expansions.
 
 The topology is described inside a module fixture: only one process at a
 time may load the TPU compiler library, so nothing here touches it while
@@ -37,6 +39,7 @@ SMOKE_SMAX = 64
 SMOKE_LIVE_TILES = {128: 1478057, 256: 1266392, 512: 1266392}
 SMOKE_FLAT_BODIES = 8 * 131072
 SMOKE_PAD_CELLS = 16384
+M2L_TABLE_ROWS, M2L_LIVE_ROWS = 1 << 23, 4219692
 
 
 @pytest.fixture(scope="module")
@@ -120,3 +123,31 @@ def test_stream_kernel_keeps_its_name_and_phase(one_chip):
     kernels = [op for op, ph in op_phases(text).items()
                if op.startswith("_p2p_stream") and ph == "fmm.p2p"]
     assert len(kernels) == 1 and kernels[0] in top_level_ops(text)
+
+
+def test_m2l_live_chunk_loop_compiles(one_chip):
+    """The M2L loop whose trip count is the traced live row count lowers
+    for the chip, and needs no more temporaries than the whole-table
+    loop."""
+    from repro.core.engine.m2l import M2L_CHUNK, m2l_accumulate, m2l_rows
+    from repro.core.multipole import get_operators
+    ops = get_operators(4)
+    cells, n = 8 * SMOKE_PAD_CELLS, M2L_TABLE_ROWS
+    args = (_sds(one_chip, (cells, ops.nk)), _sds(one_chip, (n,), jnp.int32),
+            _sds(one_chip, (n,), jnp.int32), _sds(one_chip, (n, 3)),
+            _sds(one_chip, (n,)))
+
+    def whole(M, src, tgt, d, mask):
+        return m2l_accumulate(ops, M, jnp.zeros_like(M), src, tgt, d, mask)
+
+    def live(M, src, tgt, d, mask, n_live):
+        return m2l_accumulate(ops, M, jnp.zeros_like(M), src, tgt, d, mask,
+                              n_live)
+
+    full = jax.jit(whole).lower(*args).compile()
+    part = jax.jit(live).lower(*args, _sds(one_chip, (), jnp.int32)) \
+        .compile()
+    assert part.memory_analysis().temp_size_in_bytes \
+        <= full.memory_analysis().temp_size_in_bytes
+    # the loop runs 65 of the table's 128 chunks
+    assert m2l_rows(M2L_LIVE_ROWS, n) == 65 * M2L_CHUNK == n // 128 * 65
